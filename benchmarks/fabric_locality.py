@@ -30,6 +30,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
 from repro.kvstore.fabric_sim import FabricConfig  # noqa: E402
+from repro.launch.compile_cache import configure_compile_cache  # noqa: E402
 from repro.kvstore.fleet import BatchedFabricSimulator  # noqa: E402
 from repro.kvstore.simulator import RackConfig  # noqa: E402
 from repro.kvstore.workload import Workload, WorkloadConfig  # noqa: E402
@@ -80,6 +81,7 @@ def main() -> None:
     ap.add_argument("--racks", type=int, default=4)
     ap.add_argument("--windows", type=int, default=256)
     args = ap.parse_args()
+    configure_compile_cache()
     num_keys = 20_000 if args.quick else 1_000_000
     windows = 32 if args.quick else args.windows
     warm = 8 if args.quick else 16

@@ -50,6 +50,7 @@ import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro import kernels  # noqa: E402
+from repro.launch.compile_cache import configure_compile_cache  # noqa: E402
 from repro.kvstore.fleet import BatchedRackSimulator  # noqa: E402
 from repro.kvstore.simulator import RackConfig, RackSimulator  # noqa: E402
 from repro.kvstore.workload import Workload, WorkloadConfig  # noqa: E402
@@ -253,6 +254,7 @@ def main() -> None:
     ap.add_argument("--out", default=os.path.join(REPO_ROOT,
                                                   "BENCH_simulator.json"))
     args = ap.parse_args()
+    configure_compile_cache()
     if args.points < 1 or args.windows < 1 or args.reps < 1:
         ap.error("--points, --windows and --reps must be >= 1")
 

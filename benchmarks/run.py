@@ -15,6 +15,8 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
+from repro.launch.compile_cache import configure_compile_cache  # noqa: E402
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -22,6 +24,7 @@ def main() -> None:
     ap.add_argument("--fig", type=int, default=0, help="9..18; 0 = all")
     ap.add_argument("--out", default="results/benchmarks.json")
     args = ap.parse_args()
+    configure_compile_cache()
 
     from benchmarks import figures
 

@@ -29,6 +29,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax.extend import core as jex_core
 
 PAD = 32  # tiny value payload for lint builds
 
@@ -36,7 +37,7 @@ PAD = 32  # tiny value payload for lint builds
 @dataclass
 class EntryPoint:
     name: str
-    make_jaxpr: Callable[[], jax.core.ClosedJaxpr]
+    make_jaxpr: Callable[[], jex_core.ClosedJaxpr]
     expected_pallas: dict = field(default_factory=lambda: {"ref": 0})
     donation: Callable | None = None   # () -> (jit_fn, args)
     retrace: Callable | None = None    # () -> (jit_fn, thunk_a, thunk_b, axis)

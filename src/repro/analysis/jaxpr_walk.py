@@ -25,12 +25,8 @@ from __future__ import annotations
 
 from typing import Iterator, NamedTuple
 
-import jax
-
-try:  # jax 0.4.x private layout (pinned: 0.4.37)
-    from jax._src import source_info_util
-except ImportError:  # pragma: no cover - future jax
-    source_info_util = None
+from jax._src import source_info_util
+from jax.extend import core as jex_core
 
 
 def count_pallas_calls(jaxpr) -> int:
@@ -41,15 +37,15 @@ def count_pallas_calls(jaxpr) -> int:
             n += 1
         for v in eqn.params.values():
             for sub in (v if isinstance(v, (list, tuple)) else [v]):
-                if isinstance(sub, jax.core.ClosedJaxpr):
+                if isinstance(sub, jex_core.ClosedJaxpr):
                     n += count_pallas_calls(sub.jaxpr)
-                elif isinstance(sub, jax.core.Jaxpr):
+                elif isinstance(sub, jex_core.Jaxpr):
                     n += count_pallas_calls(sub)
     return n
 
 
 class WalkItem(NamedTuple):
-    eqn: object          # jax.core.JaxprEqn
+    eqn: object          # jex_core.JaxprEqn
     path: str            # e.g. "pjit/scan[3]/eqn[12]"
     scan_depth: int      # number of enclosing lax.scan bodies
     defs: dict           # Var -> defining eqn, for the eqn's own scope
@@ -58,9 +54,9 @@ class WalkItem(NamedTuple):
 def _sub_jaxprs(eqn):
     for key, v in eqn.params.items():
         for sub in (v if isinstance(v, (list, tuple)) else [v]):
-            if isinstance(sub, jax.core.ClosedJaxpr):
+            if isinstance(sub, jex_core.ClosedJaxpr):
                 yield key, sub.jaxpr
-            elif isinstance(sub, jax.core.Jaxpr):
+            elif isinstance(sub, jex_core.Jaxpr):
                 yield key, sub
 
 
@@ -70,7 +66,7 @@ def walk_eqns(jaxpr, *, descend_into_pallas: bool = False,
     defs: dict = {}
     for eqn in jaxpr.eqns:
         for ov in eqn.outvars:
-            if isinstance(ov, jax.core.Var):
+            if isinstance(ov, jex_core.Var):
                 defs[ov] = eqn
     for i, eqn in enumerate(jaxpr.eqns):
         name = eqn.primitive.name
@@ -86,12 +82,7 @@ def walk_eqns(jaxpr, *, descend_into_pallas: bool = False,
 
 
 def _frames(eqn):
-    if source_info_util is None:
-        return []
-    try:
-        return list(source_info_util.user_frames(eqn.source_info))
-    except Exception:
-        return []
+    return list(source_info_util.user_frames(eqn.source_info.traceback))
 
 
 def user_frame_names(eqn) -> list[str]:

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.extend import core as jex_core
 
 from .findings import Finding, Severity
 from .jaxpr_walk import (
@@ -133,7 +134,7 @@ def dtype_promotion(entry) -> list[Finding]:
         if item.eqn.primitive.name not in _ACCUM_PRIMS:
             continue
         for v in item.eqn.invars:
-            if not isinstance(v, jax.core.Var):
+            if not isinstance(v, jex_core.Var):
                 continue
             src = item.defs.get(v)
             if src is None or src.primitive.name != "convert_element_type":

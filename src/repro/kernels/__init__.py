@@ -2,14 +2,16 @@
 
 Each kernel directory holds:
   kernel.py  pl.pallas_call + explicit BlockSpec VMEM tiling
-  ops.py     jitted public wrapper (interpret=True off-TPU)
-  ref.py     pure-jnp oracle (tests assert allclose across shape sweeps)
+  ops.py     public wrapper: pads to tile alignment, packs the kernel's
+             2-D blocks; ``interpret`` is a required keyword
+  ref.py     pure-jnp oracle (tests assert bit-identity across shape sweeps)
 
-Hardware adaptation (DESIGN.md §2): the switch's TCAM match and register
-scatters have no TPU analogue — the MXU-native form of both is a one-hot
-matmul, so `orbit_match` (match-action lookup) and `cms` (count-min sketch
-update/query) are formulated as 128-aligned one-hot contractions, and
-`hot_gather` turns the hot-cache row fetch into an on-chip matmul gather.
+Hardware adaptation: the switch's TCAM match and register scatters have no
+TPU analogue, so `subround` and `cms` work on 2-D one-hot selects —
+request lanes down the sublanes, table entries along the lanes — reduced
+with sums, mins and maxes over one axis, and `hot_gather` contracts the
+id-match matrix on the MXU, exactly, through 8-bit limbs.  `orbit_match`
+has no production caller and does not lower through Mosaic.
 
 Backend dispatch
 ----------------
@@ -158,7 +160,7 @@ def cms_update_query(hkey, mask, counts, block_b: int = 256):
 
 
 def hot_gather(ids, hot_ids, rows, block_b: int = 256, block_d: int = 512):
-    """Hot-row gather-by-id on the active backend."""
+    """Exact id-match sums of int32 ``rows`` on the active backend."""
     be = kernel_backend()
     if be == "ref":
         from .hot_gather.ref import hot_gather_ref

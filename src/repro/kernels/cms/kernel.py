@@ -10,7 +10,9 @@ formulation: per depth, the batch's row indices become a one-hot matrix
 
 One fused pass returns both the updated sketch and the pre-update
 estimates (the paper's servers query-then-report).  The sketch stays
-resident in VMEM ([5, 4096] i32 = 80 KiB); the batch streams in tiles.
+resident in VMEM ([5, 2048] i32 = 40 KiB at the server tracker's width);
+the batch streams in tiles.  Per-lane values are ``[TB, 1]`` columns and
+sketch rows ``[1, W]`` rows, so the one-hot is a 2-D ``[TB, W]`` select.
 """
 from __future__ import annotations
 
@@ -25,32 +27,27 @@ DEPTH = 5
 
 def _cms_kernel(idx_ref, mask_ref, counts_ref, new_counts_ref, est_ref):
     step = pl.program_id(0)
-    idx = idx_ref[...]                     # [TB, DEPTH] int32
-    msk = mask_ref[...]                    # [TB] int32
-    w = counts_ref.shape[1]
 
     @pl.when(step == 0)
     def _init():
         new_counts_ref[...] = counts_ref[...]
 
-    counts = new_counts_ref[...]           # [DEPTH, W] running
-    col = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], w), 1)
+    msk = mask_ref[...] > 0                            # [TB, 1]
+    w = counts_ref.shape[1]
+    col = jax.lax.broadcasted_iota(jnp.int32, (idx_ref.shape[0], w), 1)
     est = None
-    new_rows = []
     for d in range(DEPTH):
-        onehot = (col == idx[:, d][:, None]) & (msk[:, None] > 0)  # [TB, W]
-        oh = onehot.astype(jnp.int32)
-        row = counts[d]                    # [W]
-        q = jnp.sum(oh * row[None, :], axis=1)                     # [TB]
+        row = new_counts_ref[d:d + 1, :]               # [1, W] tile start
+        onehot = (col == idx_ref[:, d:d + 1]) & msk    # [TB, W]
+        q = jnp.sum(jnp.where(onehot, row, 0), axis=1, keepdims=True)
         est = q if est is None else jnp.minimum(est, q)
-        new_rows.append(row + jnp.sum(oh, axis=0))
-    new_counts_ref[...] = jnp.stack(new_rows)
-    est_ref[...] = jnp.where(msk > 0, est, 0)
+        new_counts_ref[d:d + 1, :] = row + jnp.sum(
+            onehot.astype(jnp.int32), axis=0, keepdims=True)
+    est_ref[...] = jnp.where(msk, est, 0)
 
 
 @partial(jax.jit, static_argnames=("block_b", "interpret"))
-def cms_update_query(idx, mask, counts, *, block_b: int = 256,
-                     interpret: bool = True):
+def cms_update_query(idx, mask, counts, *, block_b: int, interpret: bool):
     """idx: int32[B, DEPTH] row indices; mask: int32[B]; counts: int32[D, W].
 
     Returns (new_counts [D, W], est [B]) where est is the pre-update
@@ -59,21 +56,22 @@ def cms_update_query(idx, mask, counts, *, block_b: int = 256,
     b = idx.shape[0]
     d, w = counts.shape
     grid = (b // block_b,)
-    return pl.pallas_call(
+    new_counts, est = pl.pallas_call(
         _cms_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_b, DEPTH), lambda i: (i, 0)),
-            pl.BlockSpec((block_b,), lambda i: (i,)),
+            pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
             pl.BlockSpec((d, w), lambda i: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((d, w), lambda i: (0, 0)),   # resident accumulator
-            pl.BlockSpec((block_b,), lambda i: (i,)),
+            pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((d, w), jnp.int32),
-            jax.ShapeDtypeStruct((b,), jnp.int32),
+            jax.ShapeDtypeStruct((b, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(idx, mask, counts)
+    )(idx, mask.reshape(b, 1), counts)
+    return new_counts, est[:, 0]

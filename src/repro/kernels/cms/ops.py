@@ -2,7 +2,6 @@
 indices from 128-bit key hashes, pads, dispatches."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.hashing import fold_hash
@@ -17,11 +16,9 @@ def rows_for(hkey: jnp.ndarray, width: int) -> jnp.ndarray:
                      axis=-1)
 
 
-def cms_update_query(hkey, mask, counts, block_b: int = 256,
-                     interpret: bool | None = None):
+def cms_update_query(hkey, mask, counts, block_b: int = 256, *,
+                     interpret: bool):
     """Fused CMS update+query.  hkey uint32[B,4]; counts int32[DEPTH, W]."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     b = hkey.shape[0]
     idx = rows_for(hkey, counts.shape[1])
     block_b = min(block_b, max(8, b))

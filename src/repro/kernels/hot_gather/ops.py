@@ -1,7 +1,6 @@
 """Public wrapper for hot_gather: pads B/C/D to tile alignment."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from .kernel import hot_gather as _kernel
@@ -9,15 +8,14 @@ from .ref import hot_gather_ref  # noqa: F401
 
 
 def hot_gather(ids, hot_ids, rows, block_b: int = 256, block_d: int = 512,
-               interpret: bool | None = None):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+               *, interpret: bool):
+    """Exact id-match sums of int32 ``rows`` (see ``kernel.py``)."""
     b = ids.shape[0]
     c, d = rows.shape
     block_b = min(block_b, max(8, b))
     block_d = min(block_d, max(128, d))
     pad_b = (-b) % block_b
-    pad_c = (-c) % 128 if c % 128 else 0
+    pad_c = (-c) % 128
     pad_d = (-d) % block_d
     if pad_b:
         ids = jnp.pad(ids, (0, pad_b), constant_values=-2)

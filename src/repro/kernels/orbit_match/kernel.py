@@ -60,7 +60,7 @@ def _match_kernel(hkey_ref, table_ref, occ_ref, valid_ref, mask_ref,
 
 @partial(jax.jit, static_argnames=("block_b", "interpret"))
 def orbit_match(hkey, table_hkeys, occupied, valid, pop_mask, *,
-                block_b: int = 256, interpret: bool = True):
+                block_b: int, interpret: bool):
     """Batched lookup: returns (cidx [B], hit [B], valid_hit [B], pop [C]).
 
     Args:

@@ -1,23 +1,16 @@
 """Public wrapper for the orbit_match kernel: pads batch/table to hardware
-alignment, picks interpret mode off-TPU, unpads results."""
+alignment, unpads results."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from .kernel import orbit_match as _kernel
 from .ref import orbit_match_ref  # noqa: F401  (re-exported oracle)
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def orbit_match(hkey, table_hkeys, occupied, valid, pop_mask=None,
-                block_b: int = 256, interpret: bool | None = None):
+                block_b: int = 256, *, interpret: bool):
     """Batched match-action lookup (see kernel.py).  Any B, any C."""
-    if interpret is None:
-        interpret = not _on_tpu()
     b = hkey.shape[0]
     c = table_hkeys.shape[0]
     if pop_mask is None:

@@ -10,15 +10,20 @@ Tiling: the tables (hkeys, flags, queue pointers, orbit metadata) stay
 resident in VMEM across the whole grid; the request batch streams through
 in ``block_b`` tiles.  Cross-tile sequencing (a packet's slot offset
 depends on how many same-entry packets came before it in the batch) is
-carried in accumulator output blocks mapped to a fixed index — grid steps
+carried in accumulator blocks mapped to a fixed index — grid steps
 execute sequentially on a TPU core, so the running per-entry attempt
 counts, the popularity sums, and the winner grids all build up in place,
 exactly like the resident sketch accumulator in the cms kernel.
 
-(The narrower match+admission-only ``orbit_pipeline`` kernel that used to
-live here was retired once ``subround`` became the only production data
-plane; its match/admission slice survives verbatim as the first stages of
-``_subround_kernel``.)
+Layout: every array in the body is 2-D.  Request lanes run down the
+sublanes (per-lane values are ``[TB, 1]`` columns) and table entries run
+along the lanes (per-entry values are ``[1, C]`` rows), so every match and
+winner reduction is a ``[TB, C]`` select followed by a sum, min or max over
+one axis.  The per-entry tables arrive entry-minor — the request table as
+``[S, C]`` per field, orbit lines as ``[F, C]``, the serve grid leaves as
+``[J, C]`` — so a slot, fragment or serve index is a static row and no
+gather or reshape runs inside the kernel.  The recirculation budget is an
+SMEM scalar.  Floats travel as their int32 bit patterns.
 """
 from __future__ import annotations
 
@@ -27,298 +32,266 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# columns of the packed per-lane input ``lanes`` int32[B, LANE_COLS]
+(L_HK0, L_HK1, L_HK2, L_HK3, L_WANT, L_WREQ, L_INST, L_FRAG, L_NFRAGS,
+ L_KIDX, L_VLEN, L_CLIENT, L_SEQ, L_PORT, L_TS) = range(15)
+LANE_COLS = 15
+# columns of the packed per-lane output int32[B, 4]
+(O_HIT, O_VHIT, O_ACC, O_OVF) = range(4)
+# rows of the per-entry input int32[E_IN, C]
+(E_OCC, E_STV, E_STVER, E_QLEN, E_FRONT, E_REAR, E_FRAGS) = range(7)
+E_IN = 7
+# rows of the per-entry output int32[E_OUT, C]
+(E_POP, E_LKIDX, E_LVLEN, E_LVER) = (7, 8, 9, 10)
+E_OUT = 11
+# request-table fields, int32[RT_FIELDS, S, C]
+(RT_CLIENT, RT_SEQ, RT_PORT, RT_TS, RT_ACKED, RT_KIDX) = range(6)
+RT_FIELDS = 6
+# orbit-line fields, int32[OB_IN, F, C] in / int32[OB_OUT, F, C] out
+(OB_LIVE, OB_KIDX, OB_VER, OB_VLEN, OB_VWR, OB_VWN) = range(6)
+OB_IN, OB_OUT = 4, 6
+# serve-grid fields, int32[G_FIELDS, J, C]
+(G_SERVED, G_CLIENT, G_SEQ, G_PORT, G_TS, G_KIDX) = range(6)
+G_FIELDS = 6
+# kernel-internal per-entry accumulators (VMEM scratch rows)
+(A_WCNT, A_INV, A_VAL, A_NEWC) = range(4)
+
+# request-table field <- per-lane column, for the admission write and the
+# front-slot serve gather (acked is zeroed on admission, never served)
+_RT_FROM_LANE = ((RT_CLIENT, L_CLIENT), (RT_SEQ, L_SEQ), (RT_PORT, L_PORT),
+                 (RT_TS, L_TS), (RT_KIDX, L_KIDX))
+_GRID_FROM_RT = ((G_CLIENT, RT_CLIENT), (G_SEQ, RT_SEQ), (G_PORT, RT_PORT),
+                 (G_TS, RT_TS), (G_KIDX, RT_KIDX))
 
 
 def _subround_kernel(
-    # per-lane tile inputs
-    hkey_ref, want_ref, wreq_ref, inst_ref, frag_ref, nfr_ref, kidx_ref,
-    vlen_ref, client_ref, seq_ref, port_ref, ts_ref,
-    # table inputs (resident, call-time state)
-    thk_ref, occ_ref, stv_ref, stver_ref,
-    rtc_in, rts_in, rtp_in, rtts_in, rta_in, rtk_in,
-    qlen_in, front_in, rear_in,
-    olive_in, okidx_in, over_in, ovlen_in, ofrags_in,
-    budget_ref,
-    # per-lane outputs
-    hit_o, vhit_o, acc_o, ovf_o,
-    # table outputs / accumulators
-    pop_o, stv_o, stver_o,
-    rtc_o, rts_o, rtp_o, rtts_o, rta_o, rtk_o,
-    qlen_o, front_o, rear_o,
-    olive_o, okidx_o, over_o, ovlen_o, ofrags_o,
-    vwr_o, vwn_o,
-    srv_o, gcl_o, gsq_o, gpt_o, gts_o, gkx_o,
-    lkx_o, lvl_o, lvr_o,
-    # kernel-internal accumulators (discarded by the wrapper)
-    wcnt_o, inv_o, val_o, newc_o,
+    lanes_ref, thk_ref, ent_ref, rt_in, ob_in, budget_ref,
+    lane_o, ent_o, rt_o, ob_o, grid_o,
+    acc,
     *, queue_size: int, max_frags: int, max_serves: int, n_steps: int,
 ):
     """One VMEM pass per request tile over the WHOLE subround (Fig. 4).
 
-    Stages per tile (accumulated across the sequential grid like the
-    match+admission kernel above): 128-bit match + validity + popularity,
-    request-table admission AND metadata winner-gathers, the state-table
+    Stages per tile: 128-bit match + validity + popularity, request-table
+    admission AND metadata winner-selects, the state-table
     invalidate/validate one-hots, and the orbit-line install last-writer
     reduction.  At the final grid step — once the whole batch has been
     applied — the resident accumulators are finalized in place: state bits
     resolved, installed lines stamped with the post-batch entry version,
     liveness refreshed, the recirculation budget split over live lines, and
-    the request-table front slots gathered/popped into the serve grid.
+    the request-table front slots selected/popped into the serve grid.
     Value bytes never enter: install winners leave as ``vwr``/``vwn`` for
     the once-per-window byte apply.
     """
     step = pl.program_id(0)
     s, f, j = queue_size, max_frags, max_serves
-    hk = hkey_ref[...]
-    tb = thk_ref[...]
-    occ = occ_ref[...]
-    stv_in = stv_ref[...]
-    tb_n = hk.shape[0]
-    c = tb.shape[0]
+    tb_n = lanes_ref.shape[0]
+    c = thk_ref.shape[1]
     i32 = jnp.int32
+    lane = lambda k: lanes_ref[:, k:k + 1]           # [TB, 1]
+    ent = lambda k: ent_ref[k:k + 1, :]              # [1, C]
+    colsum = lambda m, v: jnp.sum(jnp.where(m, v, 0), axis=0, keepdims=True)
+    rowsum = lambda m, v: jnp.sum(jnp.where(m, v, 0), axis=1, keepdims=True)
+    colany = lambda m: jnp.max(m.astype(i32), axis=0, keepdims=True) > 0
+
+    col = jax.lax.broadcasted_iota(i32, (tb_n, c), 1)
+    lanes_c = jax.lax.broadcasted_iota(i32, (tb_n, c), 0)
 
     # ---- match slice ------------------------------------------------------
-    eq = jnp.ones((tb_n, c), dtype=jnp.bool_)
-    for lane in range(4):
-        eq = eq & (hk[:, lane][:, None] == tb[:, lane][None, :])
-    eq = eq & (occ[None, :] > 0)
-    hit = jnp.any(eq, axis=1)
-    cidx = jnp.argmax(eq, axis=1).astype(i32)
+    eq = ent(E_OCC) > 0
+    for k in range(4):
+        eq = eq & (lane(L_HK0 + k) == thk_ref[k:k + 1, :])
+    # first matching entry: a min over the masked entry iota
+    cidx = jnp.min(jnp.where(eq, col, c), axis=1, keepdims=True)
+    hit = cidx < c
     safe = jnp.where(hit, cidx, 0)
-    entry_valid = (stv_in[safe] > 0) & hit
-    hit_o[...] = hit.astype(i32)
-    vhit_o[...] = entry_valid.astype(i32)
-
-    want = want_ref[...]
-    pop_delta = jnp.sum((eq & (want[:, None] > 0)).astype(i32), axis=0)
+    at = col == safe                                 # [TB, C] one-hot
+    entry_valid = (rowsum(at, ent(E_STV)) > 0) & hit
+    want = lane(L_WANT) > 0
+    pop_delta = colsum(eq & want, 1)
 
     @pl.when(step == 0)
     def _init():
         # zero the running accumulators, seed the table outputs with the
         # call-time state — later tiles overwrite their winner slots only.
-        pop_o[...] = jnp.zeros_like(pop_o)
-        wcnt_o[...] = jnp.zeros_like(wcnt_o)
-        inv_o[...] = jnp.zeros_like(inv_o)
-        val_o[...] = jnp.zeros_like(val_o)
-        newc_o[...] = jnp.zeros_like(newc_o)
-        vwr_o[...] = jnp.zeros_like(vwr_o)
-        vwn_o[...] = jnp.zeros_like(vwn_o)
-        stver_o[...] = stver_ref[...]
-        rtc_o[...] = rtc_in[...]
-        rts_o[...] = rts_in[...]
-        rtp_o[...] = rtp_in[...]
-        rtts_o[...] = rtts_in[...]
-        rta_o[...] = rta_in[...]
-        rtk_o[...] = rtk_in[...]
-        olive_o[...] = olive_in[...]
-        okidx_o[...] = okidx_in[...]
-        ovlen_o[...] = ovlen_in[...]
-        ofrags_o[...] = ofrags_in[...]
+        acc[...] = jnp.zeros_like(acc)
+        ent_o[...] = jnp.zeros_like(ent_o)
+        ent_o[E_STVER:E_STVER + 1, :] = ent(E_STVER)
+        ent_o[E_FRAGS:E_FRAGS + 1, :] = ent(E_FRAGS)
+        rt_o[...] = rt_in[...]
+        ob_o[...] = jnp.zeros_like(ob_o)
+        ob_o[0:OB_IN] = ob_in[...]
 
     # ---- admission slice (cross-tile sequencing via wcnt) -----------------
-    qlen0 = qlen_in[...]
-    rear0 = rear_in[...]
-    want_enq = (want > 0) & hit & entry_valid
-    col = jax.lax.broadcasted_iota(i32, (tb_n, c), 1)
-    onehot = (col == safe[:, None]) & want_enq[:, None]
-    oh = onehot.astype(i32)
-    tile_prior = jnp.cumsum(oh, axis=0) - oh
-    running = wcnt_o[...]
-    offset = (jnp.sum(tile_prior * oh, axis=1)
-              + jnp.sum(oh * running[None, :], axis=1))
-    free_i = jnp.sum(oh * (s - qlen0)[None, :], axis=1)
-    rear_i = jnp.sum(oh * rear0[None, :], axis=1)
+    qlen0 = ent(E_QLEN)
+    rear0 = ent(E_REAR)
+    want_enq = want & hit & entry_valid
+    onehot = at & want_enq
+    # exclusive prefix count down the tile: a strictly lower-triangular
+    # 0/1 matmul, exact in f32 accumulation for any realistic tile
+    tri = (jax.lax.broadcasted_iota(i32, (tb_n, tb_n), 1)
+           < jax.lax.broadcasted_iota(i32, (tb_n, tb_n), 0))
+    tile_prior = jnp.dot(tri.astype(jnp.bfloat16),
+                         onehot.astype(jnp.bfloat16),
+                         preferred_element_type=jnp.float32).astype(i32)
+    running = acc[A_WCNT:A_WCNT + 1, :]
+    offset = rowsum(onehot, tile_prior + running)
+    free_i = rowsum(onehot, s - qlen0)
+    rear_i = rowsum(onehot, rear0)
     accepted = want_enq & (offset < free_i)
     overflow = want_enq & ~accepted
-    acc_o[...] = accepted.astype(i32)
-    ovf_o[...] = overflow.astype(i32)
+    for k, v in ((O_HIT, hit), (O_VHIT, entry_valid), (O_ACC, accepted),
+                 (O_OVF, overflow)):
+        lane_o[:, k:k + 1] = v.astype(i32)
 
     slot = (rear_i + offset) % s
-    flat = safe * s + slot
-    colcs = jax.lax.broadcasted_iota(i32, (tb_n, c * s), 1)
-    woh = (accepted[:, None] & (flat[:, None] == colcs)).astype(i32)
-    writ_t = jnp.any(woh > 0, axis=0)
-    gath = lambda v: jnp.sum(woh * v[:, None], axis=0)
-    rtc_o[...] = jnp.where(writ_t, gath(client_ref[...]), rtc_o[...])
-    rts_o[...] = jnp.where(writ_t, gath(seq_ref[...]), rts_o[...])
-    rtp_o[...] = jnp.where(writ_t, gath(port_ref[...]), rtp_o[...])
-    rtk_o[...] = jnp.where(writ_t, gath(kidx_ref[...]), rtk_o[...])
-    rta_o[...] = jnp.where(writ_t, 0, rta_o[...])
-    # ts is float: gather its bit pattern so the select stays exact
-    ts_bits = jax.lax.bitcast_convert_type(ts_ref[...], i32)
-    rtts_o[...] = jnp.where(
-        writ_t, jax.lax.bitcast_convert_type(gath(ts_bits), jnp.float32),
-        rtts_o[...])
+    acc_at = onehot & accepted
+    for sp in range(s):
+        woh = acc_at & (slot == sp)                  # unique writer per slot
+        writ = colany(woh)
+        for fld, src in _RT_FROM_LANE:
+            rt_o[fld, sp:sp + 1, :] = jnp.where(
+                writ, colsum(woh, lane(src)), rt_o[fld, sp:sp + 1, :])
+        rt_o[RT_ACKED, sp:sp + 1, :] = jnp.where(
+            writ, 0, rt_o[RT_ACKED, sp:sp + 1, :])
 
-    pop_o[...] = pop_o[...] + pop_delta
-    newc_o[...] = newc_o[...] + jnp.sum(oh * accepted[:, None].astype(i32),
-                                        axis=0)
-    wcnt_o[...] = running + jnp.sum(oh, axis=0)
+    ent_o[E_POP:E_POP + 1, :] += pop_delta
+    acc[A_NEWC:A_NEWC + 1, :] += colsum(acc_at, 1)
+    acc[A_WCNT:A_WCNT + 1, :] = running + colsum(onehot, 1)
 
     # ---- state-table one-hots (whole-batch apply, finalized at the end) ---
-    wreq = wreq_ref[...]
-    inst = inst_ref[...]
-    w_cached = (wreq > 0) & hit
-    install = (inst > 0) & hit
-    oh_inv = (col == safe[:, None]) & w_cached[:, None]
-    oh_val = (col == safe[:, None]) & install[:, None]
-    inv_o[...] = inv_o[...] | jnp.any(oh_inv, axis=0).astype(i32)
-    val_o[...] = val_o[...] | jnp.any(oh_val, axis=0).astype(i32)
-    stver_o[...] = stver_o[...] + jnp.sum(oh_inv.astype(i32), axis=0)
+    install = (lane(L_INST) > 0) & hit
+    oh_inv = at & (lane(L_WREQ) > 0) & hit
+    oh_val = at & install
+    acc[A_INV:A_INV + 1, :] |= colany(oh_inv).astype(i32)
+    acc[A_VAL:A_VAL + 1, :] |= colany(oh_val).astype(i32)
+    ent_o[E_STVER:E_STVER + 1, :] += colsum(oh_inv, 1)
 
     # ---- orbit-line install (last writer wins; later tiles override) ------
-    frag = frag_ref[...]
-    line = safe * f + jnp.clip(frag, 0, f - 1)
-    colcf = jax.lax.broadcasted_iota(i32, (tb_n, c * f), 1)
-    lh = install[:, None] & (line[:, None] == colcf)
-    lanes_cf = jax.lax.broadcasted_iota(i32, (tb_n, c * f), 0)
-    win_rel = jnp.max(jnp.where(lh, lanes_cf, -1), axis=0)
-    written_t = win_rel >= 0
-    sel = (lh & (lanes_cf == win_rel[None, :])).astype(i32)
-    lgath = lambda v: jnp.sum(sel * v[:, None], axis=0)
-    okidx_o[...] = jnp.where(written_t, lgath(kidx_ref[...]), okidx_o[...])
-    ovlen_o[...] = jnp.where(written_t, lgath(vlen_ref[...]), ovlen_o[...])
-    vwr_o[...] = jnp.where(written_t, win_rel + step * tb_n, vwr_o[...])
-    vwn_o[...] = vwn_o[...] | written_t.astype(i32)
-    olive_o[...] = olive_o[...] | written_t.astype(i32)
+    frag = lane(L_FRAG)
+    fr = jnp.clip(frag, 0, f - 1)
+    for fp in range(f):
+        lh = oh_val & (fr == fp)
+        win_rel = jnp.max(jnp.where(lh, lanes_c, -1), axis=0, keepdims=True)
+        written = win_rel >= 0
+        sel = lh & (lanes_c == win_rel)
+        for fld, src in ((OB_KIDX, L_KIDX), (OB_VLEN, L_VLEN)):
+            ob_o[fld, fp:fp + 1, :] = jnp.where(
+                written, colsum(sel, lane(src)), ob_o[fld, fp:fp + 1, :])
+        ob_o[OB_VWR, fp:fp + 1, :] = jnp.where(
+            written, win_rel + step * tb_n, ob_o[OB_VWR, fp:fp + 1, :])
+        ob_o[OB_VWN, fp:fp + 1, :] |= written.astype(i32)
+        ob_o[OB_LIVE, fp:fp + 1, :] |= written.astype(i32)
 
-    ehm = install & (frag == 0)
-    eh = ehm[:, None] & (col == safe[:, None])
-    lanes_c = jax.lax.broadcasted_iota(i32, (tb_n, c), 0)
-    win_e = jnp.max(jnp.where(eh, lanes_c, -1), axis=0)
-    sel_e = (eh & (lanes_c == win_e[None, :])).astype(i32)
-    nf_g = jnp.sum(sel_e * jnp.maximum(nfr_ref[...], 1)[:, None], axis=0)
-    ofrags_o[...] = jnp.where(win_e >= 0, nf_g, ofrags_o[...])
+    eh = oh_val & (frag == 0)
+    win_e = jnp.max(jnp.where(eh, lanes_c, -1), axis=0, keepdims=True)
+    sel_e = eh & (lanes_c == win_e)
+    nf_g = colsum(sel_e, jnp.maximum(lane(L_NFRAGS), 1))
+    ent_o[E_FRAGS:E_FRAGS + 1, :] = jnp.where(
+        win_e >= 0, nf_g, ent_o[E_FRAGS:E_FRAGS + 1, :])
 
     # ---- serving round: finalize once the whole batch is in ---------------
     @pl.when(step == n_steps - 1)
     def _serve():
-        stv_f = (((stv_in > 0) & (inv_o[...] == 0)) | (val_o[...] > 0))
-        stv_o[...] = stv_f.astype(i32)
-        stver_f = stver_o[...]
+        stv_f = (((ent(E_STV) > 0) & (acc[A_INV:A_INV + 1, :] == 0))
+                 | (acc[A_VAL:A_VAL + 1, :] > 0))
+        ent_o[E_STV:E_STV + 1, :] = stv_f.astype(i32)
+        stver_f = ent_o[E_STVER:E_STVER + 1, :]
 
-        # installed lines carry the post-batch entry version ([C, F] view)
-        vw2 = (vwn_o[...] > 0).reshape(c, f)
-        over2 = jnp.where(vw2, stver_f[:, None], over_in[...].reshape(c, f))
-        over_o[...] = over2.reshape(c * f)
+        # installed lines carry the post-batch entry version; drop-stale
+        # liveness refresh and the per-entry recirculation budget
+        n_live_c = jnp.zeros((1, c), i32)
+        for fp in range(f):
+            over = jnp.where(ob_o[OB_VWN, fp:fp + 1, :] > 0, stver_f,
+                             ob_in[OB_VER, fp:fp + 1, :])
+            ob_o[OB_VER, fp:fp + 1, :] = over
+            ok = ((ent(E_OCC) > 0) & stv_f & (over == stver_f)
+                  & (ob_o[OB_LIVE, fp:fp + 1, :] > 0))
+            ob_o[OB_LIVE, fp:fp + 1, :] = ok.astype(i32)
+            n_live_c = n_live_c + ok.astype(i32)
+        n_live = jnp.maximum(jnp.sum(n_live_c, axis=1, keepdims=True), 1)
+        per_line = budget_ref[0, 0] // n_live
+        complete = n_live_c >= ent_o[E_FRAGS:E_FRAGS + 1, :]
+        budget_c = jnp.where(complete, per_line, 0)
 
-        # drop-stale refresh + per-entry recirculation budget
-        live2 = olive_o[...].reshape(c, f) > 0
-        ok2 = ((occ > 0)[:, None] & stv_f[:, None]
-               & (over2 == stver_f[:, None]) & live2)
-        olive_o[...] = ok2.reshape(c * f).astype(i32)
-        n_live = jnp.maximum(jnp.sum(ok2.astype(i32)), 1)
-        per_line = budget_ref[0] // n_live
-        complete = jnp.sum(ok2.astype(i32), axis=1) >= ofrags_o[...]
-        budget_c = jnp.where(complete, per_line, 0).astype(i32)
-
-        newc = newc_o[...]
+        newc = acc[A_NEWC:A_NEWC + 1, :]
         qlen2 = qlen0 + newc
-        rear_o[...] = (rear0 + newc) % s
+        ent_o[E_REAR:E_REAR + 1, :] = (rear0 + newc) % s
 
-        jj = jax.lax.broadcasted_iota(i32, (c, j), 1)
+        front0 = ent(E_FRONT)
         n_serve = jnp.minimum(qlen2, budget_c)
-        served = jj < n_serve[:, None]
-        srv_o[...] = served.astype(i32)
-        front0 = front_in[...]
-        slot_g = (front0[:, None] + jj) % s
-        take = lambda ref: jnp.take_along_axis(
-            ref[...].reshape(c, s), slot_g, axis=1)
-        gcl_o[...] = take(rtc_o)
-        gsq_o[...] = take(rts_o)
-        gpt_o[...] = take(rtp_o)
-        gts_o[...] = take(rtts_o)
-        gkx_o[...] = take(rtk_o)
+        n_pop = jnp.zeros((1, c), i32)
+        for jj in range(j):
+            served = jj < n_serve
+            grid_o[G_SERVED, jj:jj + 1, :] = served.astype(i32)
+            n_pop = n_pop + served.astype(i32)
+            slot_g = (front0 + jj) % s
+            for gf, fld in _GRID_FROM_RT:
+                g = jnp.zeros((1, c), i32)
+                for sp in range(s):
+                    g = jnp.where(slot_g == sp, rt_o[fld, sp:sp + 1, :], g)
+                grid_o[gf, jj:jj + 1, :] = g
+        ent_o[E_QLEN:E_QLEN + 1, :] = qlen2 - n_pop
+        ent_o[E_FRONT:E_FRONT + 1, :] = (front0 + n_pop) % s
 
-        n_pop = jnp.sum(served.astype(i32), axis=1)
-        qlen_o[...] = qlen2 - n_pop
-        front_o[...] = (front0 + n_pop) % s
-
-        lkx_o[...] = okidx_o[...].reshape(c, f)[:, 0]
-        lvl_o[...] = jnp.sum(ovlen_o[...].reshape(c, f), axis=1)
-        lvr_o[...] = over2[:, 0]
+        ent_o[E_LKIDX:E_LKIDX + 1, :] = ob_o[OB_KIDX, 0:1, :]
+        ent_o[E_LVLEN:E_LVLEN + 1, :] = jnp.sum(ob_o[OB_VLEN], axis=0,
+                                                keepdims=True)
+        ent_o[E_LVER:E_LVER + 1, :] = ob_o[OB_VER, 0:1, :]
 
 
 @partial(jax.jit, static_argnames=("queue_size", "max_frags", "max_serves",
                                    "block_b", "interpret"))
-def subround(
-    hkey, want, wreq, inst, frag, nfrags, kidx, vlen, client, seq, port, ts,
-    table_hkeys, occupied, st_valid, st_version,
-    rt_client, rt_seq, rt_port, rt_ts, rt_acked, rt_kidx, qlen, front, rear,
-    ob_live, ob_kidx, ob_version, ob_vlen, ob_frags,
-    budget,
-    *, queue_size: int, max_frags: int, max_serves: int,
-    block_b: int = 128, interpret: bool = True,
-):
+def subround(lanes, table_hkeys_t, ent, rt, ob, budget, *,
+             queue_size: int, max_frags: int, max_serves: int,
+             block_b: int, interpret: bool):
     """Full fused subround (see ``_subround_kernel``).  B % block_b == 0.
 
-    Returns the 32 arrays of ``ops.SubroundOuts`` (the four trailing
-    kernel-internal accumulators are dropped here).
+    Args (all int32; ``ops.subround`` packs them from the public layout):
+      lanes: [B, LANE_COLS] per-lane columns (``L_*``).
+      table_hkeys_t: [4, C] installed key hashes, one row per hash word.
+      ent: [E_IN, C] per-entry rows (``E_*``).
+      rt: [RT_FIELDS, S, C] request-table fields (``RT_*``).
+      ob: [OB_IN, F, C] orbit-line metadata (``OB_*``).
+      budget: [1, 1] recirculation budget of this subround (SMEM).
+
+    Returns ``(lane_out [B, 4], ent_out [E_OUT, C], rt [RT_FIELDS, S, C],
+    ob_out [OB_OUT, F, C], grid [G_FIELDS, J, C])``.
     """
-    b = hkey.shape[0]
-    c = table_hkeys.shape[0]
+    b = lanes.shape[0]
+    c = table_hkeys_t.shape[1]
     s, f, j = queue_size, max_frags, max_serves
     n_steps = b // block_b
-    ent = lambda i: (0,)
-    lane = lambda i: (i,)
-    ent2 = lambda i: (0, 0)
-    i32 = jnp.int32
-    lane_spec = pl.BlockSpec((block_b,), lane)
-    c_spec = pl.BlockSpec((c,), ent)
-    cs_spec = pl.BlockSpec((c * s,), ent)
-    cf_spec = pl.BlockSpec((c * f,), ent)
-    cj_spec = pl.BlockSpec((c, j), ent2)
-    out = pl.pallas_call(
+    fixed = lambda *shape: pl.BlockSpec(shape, lambda i: (0,) * len(shape))
+    out_shape = [
+        jax.ShapeDtypeStruct((b, 4), jnp.int32),
+        jax.ShapeDtypeStruct((E_OUT, c), jnp.int32),
+        jax.ShapeDtypeStruct((RT_FIELDS, s, c), jnp.int32),
+        jax.ShapeDtypeStruct((OB_OUT, f, c), jnp.int32),
+        jax.ShapeDtypeStruct((G_FIELDS, j, c), jnp.int32),
+    ]
+    return pl.pallas_call(
         partial(_subround_kernel, queue_size=s, max_frags=f, max_serves=j,
                 n_steps=n_steps),
         grid=(n_steps,),
         in_specs=[
-            pl.BlockSpec((block_b, 4), lambda i: (i, 0)),      # hkey
-            *([lane_spec] * 10),   # want wreq inst frag nfrags kidx vlen
-                                   # client seq port
-            lane_spec,             # ts
-            pl.BlockSpec((c, 4), lambda i: (0, 0)),            # table hkeys
-            *([c_spec] * 3),       # occ, st_valid, st_version
-            *([cs_spec] * 6),      # rt client/seq/port/ts/acked/kidx
-            *([c_spec] * 3),       # qlen, front, rear
-            *([cf_spec] * 4),      # orbit live/kidx/version/vlen
-            c_spec,                # frags
-            pl.BlockSpec((1,), ent),                           # budget
+            pl.BlockSpec((block_b, LANE_COLS), lambda i: (i, 0)),
+            fixed(4, c),
+            fixed(E_IN, c),
+            fixed(RT_FIELDS, s, c),
+            fixed(OB_IN, f, c),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
-            *([lane_spec] * 4),    # hit, vhit, accepted, overflow
-            *([c_spec] * 3),       # pop, st_valid, st_version
-            *([cs_spec] * 6),      # rt client/seq/port/ts/acked/kidx
-            *([c_spec] * 3),       # qlen, front, rear
-            *([cf_spec] * 4),      # orbit live/kidx/version/vlen
-            c_spec,                # frags
-            *([cf_spec] * 2),      # val_writer, val_written
-            *([cj_spec] * 6),      # served + grid client/seq/port/ts/kidx
-            *([c_spec] * 3),       # line kidx/vlen/version
-            *([c_spec] * 4),       # wcnt, inv, val, newc (internal)
+            pl.BlockSpec((block_b, 4), lambda i: (i, 0)),
+            fixed(E_OUT, c),
+            fixed(RT_FIELDS, s, c),
+            fixed(OB_OUT, f, c),
+            fixed(G_FIELDS, j, c),
         ],
-        out_shape=[
-            *[jax.ShapeDtypeStruct((b,), i32)] * 4,
-            *[jax.ShapeDtypeStruct((c,), i32)] * 3,
-            jax.ShapeDtypeStruct((c * s,), i32),
-            jax.ShapeDtypeStruct((c * s,), i32),
-            jax.ShapeDtypeStruct((c * s,), i32),
-            jax.ShapeDtypeStruct((c * s,), jnp.float32),
-            jax.ShapeDtypeStruct((c * s,), i32),
-            jax.ShapeDtypeStruct((c * s,), i32),
-            *[jax.ShapeDtypeStruct((c,), i32)] * 3,
-            *[jax.ShapeDtypeStruct((c * f,), i32)] * 4,
-            jax.ShapeDtypeStruct((c,), i32),
-            *[jax.ShapeDtypeStruct((c * f,), i32)] * 2,
-            *[jax.ShapeDtypeStruct((c, j), i32)] * 4,
-            jax.ShapeDtypeStruct((c, j), jnp.float32),
-            jax.ShapeDtypeStruct((c, j), i32),
-            *[jax.ShapeDtypeStruct((c,), i32)] * 3,
-            *[jax.ShapeDtypeStruct((c,), i32)] * 4,
-        ],
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((4, c), jnp.int32)],
         interpret=interpret,
-    )(hkey, want, wreq, inst, frag, nfrags, kidx, vlen, client, seq, port,
-      ts, table_hkeys, occupied, st_valid, st_version,
-      rt_client, rt_seq, rt_port, rt_ts, rt_acked, rt_kidx, qlen, front,
-      rear, ob_live, ob_kidx, ob_version, ob_vlen, ob_frags, budget)
-    return out[:32]
+    )(lanes, table_hkeys_t, ent, rt, ob, budget)

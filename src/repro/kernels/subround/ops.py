@@ -1,5 +1,6 @@
-"""Public wrapper for the subround kernel: pads batch/table to hardware
-alignment, picks interpret mode off-TPU, unpads results."""
+"""Public wrapper for the subround kernel: packs the public flat layout
+into the kernel's 2-D blocks (batch and table padded to tile alignment)
+and unpacks the results."""
 from __future__ import annotations
 
 from typing import NamedTuple
@@ -7,13 +8,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from .kernel import subround as _subround_kernel
+from . import kernel as k
 from .ref import subround_ref  # noqa: F401  (oracle)
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
 
 class SubroundOuts(NamedTuple):
     """Outputs of the full fused subround op (all call-time-state shapes).
@@ -67,7 +63,7 @@ def subround(
     ob_live, ob_kidx, ob_version, ob_vlen, ob_frags,
     budget,
     queue_size: int, max_frags: int, max_serves: int,
-    block_b: int = 128, interpret: bool | None = None,
+    *, block_b: int = 128, interpret: bool,
 ) -> SubroundOuts:
     """Padded public wrapper for the full subround kernel.  Any B, any C.
 
@@ -77,46 +73,59 @@ def subround(
     count, or the per-entry serve budget; results are sliced back to the
     caller's shapes.
     """
-    if interpret is None:
-        interpret = not _on_tpu()
     b = hkey.shape[0]
     c = table_hkeys.shape[0]
     s, f, j = queue_size, max_frags, max_serves
+    i32 = jnp.int32
+    bits = lambda a: jax.lax.bitcast_convert_type(a, i32)
     block_b = min(block_b, max(8, b))
     pad_b = (-b) % block_b
-    pad_c = (-c) % 128 if c % 128 else 0
-    if pad_b:
-        z = lambda a: jnp.pad(a, (0, pad_b))
-        hkey = jnp.pad(hkey, ((0, pad_b), (0, 0)))
-        want, wreq, inst = z(want), z(wreq), z(inst)
-        frag, nfrags, kidx, vlen = z(frag), z(nfrags), z(kidx), z(vlen)
-        client, seq, port, ts = z(client), z(seq), z(port), z(ts)
-    if pad_c:
-        zc = lambda a: jnp.pad(a, (0, pad_c))
-        pad_rows = lambda a, w: jnp.pad(
-            a.reshape(c, w), ((0, pad_c), (0, 0))).reshape((c + pad_c) * w)
-        table_hkeys = jnp.pad(table_hkeys, ((0, pad_c), (0, 0)))
-        occupied, st_valid, st_version = zc(occupied), zc(st_valid), zc(st_version)
-        rt_client, rt_seq, rt_port = (pad_rows(rt_client, s),
-                                      pad_rows(rt_seq, s), pad_rows(rt_port, s))
-        rt_ts, rt_acked, rt_kidx = (pad_rows(rt_ts, s), pad_rows(rt_acked, s),
-                                    pad_rows(rt_kidx, s))
-        qlen, front, rear = zc(qlen), zc(front), zc(rear)
-        ob_live, ob_kidx = pad_rows(ob_live, f), pad_rows(ob_kidx, f)
-        ob_version, ob_vlen = pad_rows(ob_version, f), pad_rows(ob_vlen, f)
-        ob_frags = zc(ob_frags)
-    out = _subround_kernel(
-        hkey, want, wreq, inst, frag, nfrags, kidx, vlen, client, seq, port,
-        ts, table_hkeys, occupied, st_valid, st_version,
-        rt_client, rt_seq, rt_port, rt_ts, rt_acked, rt_kidx, qlen, front,
-        rear, ob_live, ob_kidx, ob_version, ob_vlen, ob_frags,
-        jnp.asarray(budget, jnp.int32).reshape(1),
+    pad_c = (-c) % 128
+
+    # per-lane columns -> [B, LANE_COLS]
+    cols = [bits(hkey)[:, w] for w in range(4)] + [
+        want, wreq, inst, frag, nfrags, kidx, vlen, client, seq, port,
+        bits(ts)]
+    lanes = jnp.pad(jnp.stack([a.astype(i32) for a in cols], axis=1),
+                    ((0, pad_b), (0, 0)))
+    # per-entry tables, entry-minor: [rows, C] / [fields, S or F, C]
+    pad_e = lambda a: jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad_c)])
+    minor = lambda a, w: a.reshape(c, w).T
+    thk_t = pad_e(bits(table_hkeys).T)
+    ent = pad_e(jnp.stack([a.astype(i32) for a in (
+        occupied, st_valid, st_version, qlen, front, rear, ob_frags)]))
+    rt = pad_e(jnp.stack([minor(a, s) for a in (
+        rt_client, rt_seq, rt_port, bits(rt_ts), rt_acked, rt_kidx)]))
+    ob = pad_e(jnp.stack([minor(a.astype(i32), f) for a in (
+        ob_live, ob_kidx, ob_version, ob_vlen)]))
+
+    lane_o, ent_o, rt_o, ob_o, grid_o = k.subround(
+        lanes, thk_t, ent, rt, ob, jnp.asarray(budget, i32).reshape(1, 1),
         queue_size=s, max_frags=f, max_serves=j,
-        block_b=block_b, interpret=interpret,
+        block_b=block_b, interpret=interpret)
+
+    f32 = lambda a: jax.lax.bitcast_convert_type(a, jnp.float32)
+    lane_out = lambda col: lane_o[:b, col]
+    ent_out = lambda row: ent_o[row, :c]
+    flat = lambda a: a[:, :c].T.reshape(-1)        # [W, C] -> [C * W]
+    grid = lambda fld: grid_o[fld, :, :c].T        # [J, C] -> [C, J]
+    return SubroundOuts(
+        hit=lane_out(k.O_HIT), vhit=lane_out(k.O_VHIT),
+        accepted=lane_out(k.O_ACC), overflow=lane_out(k.O_OVF),
+        pop=ent_out(k.E_POP), st_valid=ent_out(k.E_STV),
+        st_version=ent_out(k.E_STVER),
+        rt_client=flat(rt_o[k.RT_CLIENT]), rt_seq=flat(rt_o[k.RT_SEQ]),
+        rt_port=flat(rt_o[k.RT_PORT]), rt_ts=f32(flat(rt_o[k.RT_TS])),
+        rt_acked=flat(rt_o[k.RT_ACKED]), rt_kidx=flat(rt_o[k.RT_KIDX]),
+        qlen=ent_out(k.E_QLEN), front=ent_out(k.E_FRONT),
+        rear=ent_out(k.E_REAR),
+        ob_live=flat(ob_o[k.OB_LIVE]), ob_kidx=flat(ob_o[k.OB_KIDX]),
+        ob_version=flat(ob_o[k.OB_VER]), ob_vlen=flat(ob_o[k.OB_VLEN]),
+        ob_frags=ent_out(k.E_FRAGS),
+        val_writer=flat(ob_o[k.OB_VWR]), val_written=flat(ob_o[k.OB_VWN]),
+        served=grid(k.G_SERVED), g_client=grid(k.G_CLIENT),
+        g_seq=grid(k.G_SEQ), g_port=grid(k.G_PORT),
+        g_ts=f32(grid(k.G_TS)), g_kidx=grid(k.G_KIDX),
+        line_kidx=ent_out(k.E_LKIDX), line_vlen=ent_out(k.E_LVLEN),
+        line_version=ent_out(k.E_LVER),
     )
-    o = SubroundOuts(*out)
-    cut = {1: lambda a: a[:b], 2: lambda a: a[:c], 3: lambda a: a[:c * s],
-           4: lambda a: a[:c * f], 5: lambda a: a[:c]}
-    kinds = (1, 1, 1, 1, 2, 2, 2, 3, 3, 3, 3, 3, 3, 2, 2, 2,
-             4, 4, 4, 4, 2, 4, 4, 5, 5, 5, 5, 5, 5, 2, 2, 2)
-    return SubroundOuts(*(cut[k](a) for k, a in zip(kinds, o)))

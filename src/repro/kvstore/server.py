@@ -171,7 +171,7 @@ def server_step(
         [s_op == OP_R_REQ, s_op == OP_W_REQ, s_op == OP_F_REQ, s_op == OP_CRN_REQ],
         [OP_R_REP, OP_W_REP, OP_F_REP, OP_R_REP],
         OP_R_REP,
-    )
+    ).astype(jnp.int32)  # strong dtype: a weak one retraces the next chunk
     carries_val = (s_op == OP_R_REQ) | (s_op == OP_CRN_REQ) | (s_op == OP_F_REQ) | \
                   ((s_op == OP_W_REQ) & (s_flag >= 1))
     rep_flag = jnp.where(

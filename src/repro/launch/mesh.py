@@ -2,40 +2,24 @@
 
 ``make_production_mesh`` is a FUNCTION (importing this module never touches
 jax device state): 16x16 = 256 chips per pod ('data','model'); multi-pod
-adds a leading 'pod' axis -> (2,16,16) = 512 chips.
-
-``jax.sharding.AxisType`` (and ``jax.make_mesh``'s ``axis_types`` kwarg)
-only exist on newer jax; the pinned 0.4.37 has neither.  All mesh
-construction goes through :func:`make_mesh_compat`, which passes
-``AxisType.Auto`` axes where supported and falls back to the plain mesh
-(the 0.4.x default semantics — every axis implicitly Auto) otherwise.
+adds a leading 'pod' axis -> (2,16,16) = 512 chips.  Every axis is
+``AxisType.Auto``: shardings propagate as in GSPMD.
 """
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # pinned 0.4.37: axes are implicitly Auto
-    _AxisType = None
-
-
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if _AxisType is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(_AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1):
     """Small mesh over whatever devices exist (tests / examples)."""
     n = len(jax.devices())
     data = n // model
-    return make_mesh_compat((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

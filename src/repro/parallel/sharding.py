@@ -98,39 +98,6 @@ def make_ctx(mesh: Mesh, sequence_parallel: bool = False) -> ShardingCtx:
         seq_axis="model" if sequence_parallel else None))
 
 
-def shard_map_compat(*, mesh, in_specs, out_specs):
-    """Decorator form of shard_map across jax versions.
-
-    Newer jax exposes ``jax.shard_map`` (replication check flag
-    ``check_vma``); the pinned 0.4.37 only has
-    ``jax.experimental.shard_map.shard_map`` (flag ``check_rep``).  Both
-    checks are disabled: the ring steps squeeze/unsqueeze the sharded axis
-    themselves, which the checker cannot see through.
-    """
-    if hasattr(jax, "shard_map"):
-        def deco(f):
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        return deco
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def deco(f):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-    return deco
-
-
-def axis_size_compat(axis_name) -> int:
-    """Static mesh-axis size inside shard_map, across jax versions.
-
-    ``jax.lax.axis_size`` is new; on 0.4.x ``psum(1, axis)`` short-circuits
-    to a Python int at trace time.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def with_sharding(ctx: Optional[ShardingCtx], x, *axes: Optional[str]):
     """``lax.with_sharding_constraint`` if a mesh is active, else identity."""
     if ctx is None:

@@ -30,7 +30,6 @@ from jax.sharding import PartitionSpec as P
 from repro.core import distributed as ring
 from repro.core.hashing import hash128_u32
 from repro.core.types import OP_R_REQ, PacketBatch
-from repro.parallel.sharding import axis_size_compat
 
 
 class ServiceConfig(NamedTuple):
@@ -84,7 +83,7 @@ def service_step_local(st: ServiceState, keys: jnp.ndarray,
     ax = axis_names if isinstance(axis_names, tuple) else (axis_names,)
     d = 1
     for a in ax:
-        d *= axis_size_compat(a)
+        d *= jax.lax.axis_size(a)
     keys_local = st.store_keys.shape[-1]
     b = keys.shape[0]
 
@@ -160,11 +159,11 @@ def make_service_step(mesh, axis_names, cfg: ServiceConfig):
     sspec = ServiceState(ring=rspec, store_vals=spec, store_keys=spec)
     serve_spec = ring.RingServe(*([spec] * len(ring.RingServe._fields)))
 
-    from repro.parallel.sharding import shard_map_compat
-
-    @shard_map_compat(mesh=mesh,
-                      in_specs=(sspec, spec, spec),
-                      out_specs=(sspec, spec, spec, spec, serve_spec))
+    # The replication check is off: it cannot see through the manual
+    # squeeze/unsqueeze of the ring axis.
+    @partial(jax.shard_map, mesh=mesh,
+             in_specs=(sspec, spec, spec),
+             out_specs=(sspec, spec, spec, spec, serve_spec), check_vma=False)
     def step(st: ServiceState, keys, mask):
         sq = lambda t: jax.tree.map(
             lambda s, x: x.reshape(x.shape[1:]) if s == spec else x, t[0], t[1])

@@ -10,13 +10,13 @@ SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np, jax, jax.numpy as jnp
-from repro.launch.mesh import make_mesh_compat
 from repro.core import distributed as dist
 from repro.core.hashing import hash128_u32, hash128_u32_np
 from repro.core.types import OP_R_REQ, OP_NONE, PacketBatch
 
 D, C, S, L, PAD, B = 8, 16, 4, 4, 64, 8
-mesh = make_mesh_compat((D,), ("data",))
+mesh = jax.make_mesh((D,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 st0 = dist.init_ring_state(C, S, L, PAD)
 st = st0._replace(
     reqtab=jax.tree.map(lambda x: jnp.broadcast_to(x, (D,)+x.shape).copy(), st0.reqtab),

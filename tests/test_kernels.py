@@ -1,4 +1,6 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps + hypothesis."""
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -6,12 +8,18 @@ import pytest
 
 from repro import kernels
 from repro.core.hashing import hash128_u32
-from repro.kernels.cms.ops import cms_update_query, rows_for
+from repro.kernels.cms import ops as cms_ops
+from repro.kernels.cms.ops import rows_for
 from repro.kernels.cms.ref import cms_update_query_fast, cms_update_query_ref
-from repro.kernels.hot_gather.ops import hot_gather
+from repro.kernels.hot_gather import ops as hot_gather_ops
 from repro.kernels.hot_gather.ref import hot_gather_ref
-from repro.kernels.orbit_match.ops import orbit_match
+from repro.kernels.orbit_match import ops as orbit_match_ops
 from repro.kernels.orbit_match.ref import orbit_match_ref
+
+# the kernels under the Pallas interpreter, against their oracles
+cms_update_query = partial(cms_ops.cms_update_query, interpret=True)
+hot_gather = partial(hot_gather_ops.hot_gather, interpret=True)
+orbit_match = partial(orbit_match_ops.orbit_match, interpret=True)
 
 RNG = np.random.default_rng(42)
 
@@ -137,21 +145,26 @@ def test_orbit_match_mask_parity():
         np.testing.assert_array_equal(np.asarray(pop), want)
 
 
-@pytest.mark.parametrize("b,c,d,dt", [
-    (64, 32, 128, jnp.float32),
-    (500, 128, 300, jnp.bfloat16),
-    (8, 512, 64, jnp.float32),
-    (1024, 64, 1024, jnp.bfloat16),
+def _int32_rows(c, d):
+    """Rows over the whole int32 range: every 8-bit limb and the sign."""
+    info = np.iinfo(np.int32)
+    return jnp.asarray(RNG.integers(info.min, info.max, (c, d),
+                                    endpoint=True), jnp.int32)
+
+
+@pytest.mark.parametrize("b,c,d,dup", [
+    (64, 32, 128, False),
+    (500, 128, 300, True),     # repeated hot ids: matching rows are summed
+    (8, 512, 64, False),
+    (1024, 64, 1024, True),
 ])
-def test_hot_gather_sweep(b, c, d, dt):
+def test_hot_gather_sweep(b, c, d, dup):
     ids = jnp.asarray(RNG.integers(0, 4 * c, b), jnp.int32)
-    hot = jnp.asarray(np.sort(RNG.choice(4 * c, c, replace=False)), jnp.int32)
-    rows = jnp.asarray(RNG.normal(size=(c, d)), dt)
+    hot = jnp.asarray(np.sort(RNG.choice(4 * c, c, replace=dup)), jnp.int32)
+    rows = _int32_rows(c, d)
     out, hit = hot_gather(ids, hot, rows)
     want, hit_w = hot_gather_ref(ids, hot, rows)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(want, np.float32),
-                               rtol=2e-2, atol=2e-2)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
     np.testing.assert_array_equal(np.asarray(hit), np.asarray(hit_w))
 
 
@@ -175,13 +188,13 @@ def test_hot_gather_all_misses():
     b, c, d = 33, 16, 128
     ids = jnp.asarray(RNG.integers(1000, 2000, b), jnp.int32)
     hot = jnp.arange(c, dtype=jnp.int32)
-    rows = jnp.asarray(RNG.normal(size=(c, d)), jnp.float32)
+    rows = _int32_rows(c, d)
     out, hit = hot_gather(ids, hot, rows)
     want, hit_w = hot_gather_ref(ids, hot, rows)
     assert int(np.asarray(hit).sum()) == 0
+    assert not np.asarray(out).any()
     np.testing.assert_array_equal(np.asarray(hit), np.asarray(hit_w))
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                               rtol=2e-2, atol=2e-2)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
 
 
 # ---------------------------------------------------------------------------

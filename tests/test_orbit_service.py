@@ -10,12 +10,12 @@ import pytest
 SCRIPT = r'''import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np, jax, jax.numpy as jnp
-from repro.launch.mesh import make_mesh_compat
 from repro.serving import orbit_service as svc
 from repro.core.hashing import hash128_u32_np
 
 D = 8
-mesh = make_mesh_compat((D,), ("data",))
+mesh = jax.make_mesh((D,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 cfg = svc.ServiceConfig(num_entries=16, queue_size=4, slice_len=4,
                         value_pad=32, local_batch=16, a2a_quota=8)
 NUM_KEYS = 64

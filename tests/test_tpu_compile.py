@@ -1,0 +1,113 @@
+"""The main-path Pallas kernels compile through Mosaic for a TPU v5e.
+
+Each test lowers and compiles one kernel for a described (not attached)
+v5e chip, at the widths the paper rack hands it: a subround of the
+rack's 1,344-lane window (336 lanes) against 128 entries with 8-slot
+queues, the server tracker's 5 x 2,048 sketch vmapped over 32 servers,
+and the controller's 2,048 report lanes (32 servers x top-64) with int32
+rows.  Nothing runs: this guards Mosaic lowering at no chip time.
+
+The topology is described inside a module-scoped fixture — never at
+import — so every test worker collects the same tests and only the worker
+that runs this file loads the TPU compiler.  The persistent compilation
+cache is off around these compiles: an entry written for a described chip
+cannot be read back without one.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro import kernels
+
+LANES, ENTRIES, QUEUE, SERVES = 336, 128, 8, 8
+SKETCH_W, SERVERS, BATCH = 2048, 32, 1344
+REPORT_LANES = 32 * 64
+FLEET = 8
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def pallas(no_persistent_cache):
+    kernels.set_kernel_backend("pallas")
+    yield
+    kernels.set_kernel_backend(None)
+
+
+def _spec(sharding, shape, dtype=jnp.int32, fleet=None):
+    shape = shape if fleet is None else (fleet,) + shape
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _subround_args(sharding, f, fleet=None):
+    b, c, s = LANES, ENTRIES, QUEUE
+    lane = lambda dt=jnp.int32: _spec(sharding, (b,), dt, fleet)
+    ent = lambda w=1, dt=jnp.int32: _spec(sharding, (c * w,), dt, fleet)
+    return [
+        _spec(sharding, (b, 4), jnp.uint32, fleet),
+        *[lane() for _ in range(10)], lane(jnp.float32),
+        _spec(sharding, (c, 4), jnp.uint32, fleet), ent(), ent(), ent(),
+        ent(s), ent(s), ent(s), ent(s, jnp.float32), ent(s), ent(s),
+        ent(), ent(), ent(),
+        ent(f), ent(f), ent(f), ent(f), ent(),
+        _spec(sharding, (), jnp.int32, fleet),
+    ]
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("f,fleet", [(1, None), (4, None), (1, FLEET),
+                                     (4, FLEET)])
+def test_subround_compiles(one_chip, pallas, f, fleet):
+    fn = lambda *a: kernels.subround(*a, QUEUE, f, SERVES)
+    if fleet is not None:
+        fn = jax.vmap(fn)
+    compiled = _compile(fn, *_subround_args(one_chip, f, fleet))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_cms_compiles_at_tracker_widths(one_chip, pallas):
+    fn = jax.vmap(lambda m, c, h: kernels.cms_update_query(h, m, c),
+                  in_axes=(0, 0, None))
+    compiled = _compile(
+        fn, _spec(one_chip, (SERVERS, BATCH)),
+        _spec(one_chip, (SERVERS, 5, SKETCH_W)),
+        _spec(one_chip, (BATCH, 4), jnp.uint32))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("n_ids,n_hot", [(ENTRIES, REPORT_LANES),
+                                         (REPORT_LANES, REPORT_LANES),
+                                         (REPORT_LANES, ENTRIES)])
+def test_hot_gather_compiles_with_int32_rows(one_chip, pallas, n_ids, n_hot):
+    compiled = _compile(
+        kernels.hot_gather, _spec(one_chip, (n_ids,)),
+        _spec(one_chip, (n_hot,)), _spec(one_chip, (n_hot, 1)))
+    assert "tpu_custom_call" in compiled.as_text()
