@@ -23,14 +23,13 @@ compared against the median of the same-host history entries; a >20% drop
 exits nonzero (CI-able perf regression gate).  When no same-host history
 exists the check only warns — cross-host numbers are not comparable.
 
-Breakdown mode (``--breakdown``): times the window's stages in isolation
-(client generation, the fused switch ``window_pipeline``, the full
-``window_step``) and prints a compiled-HLO summary of the measured chunk
-via the shared ``repro.analysis.hlo`` tooling (instruction/fusion
-counts, custom calls — the fused-kernel count shows here on the Pallas
-backends — plus any scatter ops that survived XLA fusion outside the
-lint allowlist), so a perf diff can be attributed to a stage before
-bisecting.
+Breakdown mode (``--breakdown``): prints a compiled-HLO summary of the
+measured chunk via the shared ``repro.analysis.hlo`` tooling
+(instruction/fusion counts, custom calls — the fused-kernel count shows
+here on the Pallas backends — plus any scatter ops that survived XLA
+fusion outside the lint allowlist).  Time per window stage is measured
+on the chip, from the program's named stages (``repro.obs``), by
+``benchmarks/chip/stage_split.py``.
 """
 from __future__ import annotations
 
@@ -144,60 +143,19 @@ def check_regression(history: list[dict], run: dict,
     return 0
 
 
-def run_breakdown(sim, wl, reps: int = 30) -> dict:
-    """Per-stage timings + compiled-HLO summary for the serial window.
+def run_breakdown(sim, wl) -> dict:
+    """Compiled-HLO summary of the serial window chunk.
 
-    Stages are timed on their own jitted closures (compile excluded):
-    ``ingress_gen`` (the production ``simulator.generate_ingress`` —
-    open-loop request generation + subround-major ingress assembly),
-    ``switch_pipeline`` (the fused kernel-backed ``window_pipeline`` alone
-    — the data plane), and ``full_window`` (everything incl.
-    servers/clients/routing).  The HLO summary (``analysis.hlo``) counts
-    instructions per opcode in the compiled measured chunk — on the
-    Pallas backends the fused subround shows up as one custom call per
-    subround — and reports scatter ops that survived XLA fusion, split
-    by the ``no-scatter`` lint allowlist.
+    The summary (``analysis.hlo``) counts instructions per opcode in the
+    compiled measured chunk — on the Pallas backends the fused subround
+    shows up as one custom call per subround — and reports scatter ops
+    that survived XLA fusion, split by the ``no-scatter`` lint allowlist.
     """
     from repro.analysis.hlo import opcode_summary, scatter_instructions
     from repro.analysis.rules import ALLOWLISTS
-    from repro.core import pipeline
-    from repro.kvstore import simulator as sim_mod
 
-    cfg, scfg, ccfg = sim.cfg, sim.server_cfg, sim.client_cfg
     carry = sim.carry
     arrs = wl.arrays
-
-    def gen(cr):
-        return sim_mod.generate_ingress(cfg, ccfg, arrs, cr)
-
-    _, _, _, sub = jax.jit(gen)(carry)
-
-    def pipe_fn(sw, sb):
-        return pipeline.window_pipeline(
-            sw, sb, recirc_gbps=cfg.recirc_gbps, window_us=cfg.window_us,
-            subrounds=cfg.subrounds, max_serves=cfg.max_serves,
-            key_size=sim.key_size)
-
-    def win_fn(w, cr):
-        return sim_mod.window_step(cfg, scfg, ccfg, sim.key_size, w, cr)
-
-    stages = {
-        "ingress_gen": (jax.jit(gen), (carry,)),
-        "switch_pipeline": (jax.jit(pipe_fn), (carry.policy, sub)),
-        "full_window": (jax.jit(win_fn), (arrs, carry)),
-    }
-    timings = {}
-    for name, (fn, fargs) in stages.items():
-        jax.block_until_ready(fn(*fargs))  # compile outside the clock
-        t0 = time.time()
-        for _ in range(reps):
-            out = fn(*fargs)
-        jax.block_until_ready(out)
-        timings[name] = (time.time() - t0) / reps
-    for name, dt in sorted(timings.items(), key=lambda kv: kv[1]):
-        frac = dt / max(timings["full_window"], 1e-12)
-        print(f"breakdown,{name},{dt * 1e3:.3f},ms_per_window,"
-              f"{frac:.2f},of_full_window", flush=True)
 
     # compiled-HLO summary of the measured chunk (shared repro.analysis
     # tooling — the same parse the lint subsystem runs on every PR)
@@ -227,7 +185,6 @@ def run_breakdown(sim, wl, reps: int = 30) -> dict:
         print(f"hlo_scatter_unexpected,{s['opcode']},"
               f"{s['op_name'] or s['name']},{s['source']}", flush=True)
     return {
-        "stage_ms": {k: v * 1e3 for k, v in timings.items()},
         "hlo": {"total_instructions": summary.total,
                 "computations": summary.computations,
                 "custom_calls": summary.custom_calls,
@@ -249,8 +206,7 @@ def main() -> None:
                     help="exit nonzero on a >20%% batched-windows/sec "
                          "regression vs the same-host history median")
     ap.add_argument("--breakdown", action="store_true",
-                    help="also time window stages in isolation and print a "
-                         "compiled-HLO summary")
+                    help="also print a compiled-HLO summary of the chunk")
     ap.add_argument("--out", default=os.path.join(REPO_ROOT,
                                                   "BENCH_simulator.json"))
     args = ap.parse_args()

@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import kernels as kn
+from repro.obs import stage
 
 from . import orbit as ob
 from .types import (
@@ -298,14 +299,15 @@ def install_window_values(
     dense ``[C*F, value_pad]`` temporary — the win is on the ``val``
     copy/write side, not the gather.)
     """
-    r, cf = val_written.shape
-    # last subround with an install, per line
-    rev = val_written[::-1]
-    r_star = (r - 1 - jnp.argmax(rev, axis=0)).astype(jnp.int32)   # [C*F]
-    any_w = jnp.any(val_written, axis=0)
-    lane = jnp.take_along_axis(val_writer, r_star[None, :], axis=0)[0]
-    lines = jnp.where(any_w, jnp.arange(cf, dtype=jnp.int32), cf)
-    return val.at[lines].set(batch_val[r_star, lane], mode='drop')
+    with stage("repro.install"):
+        r, cf = val_written.shape
+        # last subround with an install, per line
+        rev = val_written[::-1]
+        r_star = (r - 1 - jnp.argmax(rev, axis=0)).astype(jnp.int32)  # [C*F]
+        any_w = jnp.any(val_written, axis=0)
+        lane = jnp.take_along_axis(val_writer, r_star[None, :], axis=0)[0]
+        lines = jnp.where(any_w, jnp.arange(cf, dtype=jnp.int32), cf)
+        return val.at[lines].set(batch_val[r_star, lane], mode='drop')
 
 
 def switch_pipeline(

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 
 from repro.baselines.netcache import netcache_install
 from repro.core.controller import CacheController, ControllerConfig
+from repro.obs import span
 
 from . import client as cl
 from .simulator import (
@@ -215,7 +216,8 @@ class BatchedRackSimulator:
         objects; the stacked device arrays are rebuilt here.  The
         stacked-vs-shared axes normally come out unchanged (churn does not
         change which points differ), so the compiled chunks are reused."""
-        self._wl, self._wl_axes = self._wl_and_axes()
+        with span("repro.churn"):
+            self._wl, self._wl_axes = self._wl_and_axes()
 
     # ---------------------------------------------------------- workload axes
     def _wl_and_axes(self) -> tuple[WorkloadArrays, WorkloadArrays]:
@@ -306,7 +308,8 @@ class BatchedRackSimulator:
         """Advance every point ``n`` windows; traces are [N, n, ...]."""
         carry, ys = self._chunk(n, self._wl_axes)(self._wl, self.carry)
         self.carry = carry
-        return {k: np.asarray(v) for k, v in ys._asdict().items()}
+        with span("repro.fetch"):
+            return {k: np.asarray(v) for k, v in ys._asdict().items()}
 
     def run_periods(self, n_periods: int, period_w: int) -> dict[str, np.ndarray]:
         """Advance every point ``n_periods`` control-plane periods of
@@ -321,10 +324,11 @@ class BatchedRackSimulator:
                           jnp.int32)
         carry, act, ys, upds = chunk(self._wl, self.carry, act)
         self.carry = carry
-        for i, c in enumerate(self.controllers):
-            c.active_size = int(act[i])
-        self._last_update = jax.tree.map(np.asarray, upds)
-        return {k: np.asarray(v) for k, v in ys._asdict().items()}
+        with span("repro.fetch"):
+            for i, c in enumerate(self.controllers):
+                c.active_size = int(act[i])
+            self._last_update = jax.tree.map(np.asarray, upds)
+            return {k: np.asarray(v) for k, v in ys._asdict().items()}
 
     def run(self, sim_seconds: float, chunk_windows: int = 256,
             controller_period_s: float | None = None) -> list[SimResult]:
@@ -345,8 +349,9 @@ class BatchedRackSimulator:
                              self.run_windows)
         merged = {k: np.concatenate([t[k] for t in traces], axis=1)
                   for k in traces[0]}
-        hist_sw = np.asarray(self.carry.clients.hist_switch)
-        hist_srv = np.asarray(self.carry.clients.hist_server)
+        with span("repro.fetch"):
+            hist_sw = np.asarray(self.carry.clients.hist_switch)
+            hist_srv = np.asarray(self.carry.clients.hist_server)
         results = []
         for i in range(self.n_points):
             res = SimResult(

@@ -41,6 +41,8 @@ from repro.core.types import (
     PacketBatch,
     sat_add,
 )
+from repro.obs import stage
+
 from .store import synth_value
 
 
@@ -145,7 +147,9 @@ def server_step(
         per_srv_mask = onehot & is_read[:, None]          # [B, n]
         def _track(tr, mask_col):
             return track_fused(tr, pkts.kidx, mask_col)
-        st = st._replace(tracker=jax.vmap(_track)(st.tracker, per_srv_mask.T))
+        with stage("repro.sketch"):
+            st = st._replace(tracker=jax.vmap(_track)(st.tracker,
+                                                      per_srv_mask.T))
 
     # ---- serve up to cap per server ----------------------------------------
     j = jnp.arange(cap)[None, :]                       # [1, cap]
@@ -160,9 +164,10 @@ def server_step(
     # write versions bump before value generation
     num_keys = st.key_version.shape[0]
     w_mask = live & (s_op == OP_W_REQ)
-    kv = st.key_version.at[jnp.where(w_mask, s_kidx, num_keys).reshape(-1)].add(
-        1, mode='drop')
-    version = kv[s_kidx]                               # [n, cap]
+    with stage("repro.key_version"):
+        kv = st.key_version.at[
+            jnp.where(w_mask, s_kidx, num_keys).reshape(-1)].add(1, mode='drop')
+        version = kv[s_kidx]                           # [n, cap]
 
     # reply op + FLAG (fragment count where a value is attached)
     true_vlen = s_vlen                                  # set by client from workload

@@ -16,9 +16,15 @@ Time advances in windows (default 100 µs).  Each window:
      a recirculation-interval latency model;
   4. server replies become next window's switch ingress.
 
-The inner loop is one jitted ``lax.scan`` per chunk; the control plane
-(cache updates, top-k reports, dynamic sizing, workload churn) runs on the
-host between chunks, exactly like the paper's switch-CPU controller.
+The inner loop is one jitted ``lax.scan`` per chunk.  The control plane
+(cache updates, top-k reports, dynamic sizing) runs traced inside that
+scan at every period boundary (:func:`controller_window_apply`), as the
+paper's switch-CPU controller runs beside the data plane; only workload
+churn runs on the host between chunks.
+
+Each stage of the window is a named scope of :mod:`repro.obs` (``repro.gen``,
+``repro.switch``, ``repro.server``, ``repro.account``, ``repro.controller``),
+so a device trace attributes every op to its stage.
 
 Hot-path layout: every ingress source is kept **subround-major** ``[R, L]``
 (clients emit it directly, server replies are interleaved once before they
@@ -68,6 +74,7 @@ from repro.core.types import (
     sat_add,
 )
 from repro.baselines.nocache import nocache_step
+from repro.obs import stage
 
 from . import client as cl
 from .server import (
@@ -288,14 +295,17 @@ def controller_window_apply(
     top_est)`` report arrays (the fabric's spine controller merges them
     across racks).
     """
-    servers, top_k, top_e = server_reports_traced(carry.servers,
-                                                  ctrl_cfg.k_report)
-    sw = carry.policy
-    sw2, active2, upd = controller_step(
-        sw, top_k.reshape(-1), top_e.reshape(-1),
-        sw.counters.overflow, sw.counters.cached_reqs, active_size, ctrl_cfg,
-    )
-    fetch = traced_fetch_batch(cfg, wl.vlen, upd.fetch_kidx, upd.fetch_valid)
+    with stage("repro.controller"):
+        servers, top_k, top_e = server_reports_traced(carry.servers,
+                                                      ctrl_cfg.k_report)
+        sw = carry.policy
+        sw2, active2, upd = controller_step(
+            sw, top_k.reshape(-1), top_e.reshape(-1),
+            sw.counters.overflow, sw.counters.cached_reqs, active_size,
+            ctrl_cfg,
+        )
+        fetch = traced_fetch_batch(cfg, wl.vlen, upd.fetch_kidx,
+                                   upd.fetch_valid)
     return (carry._replace(policy=sw2, servers=servers, fetch=fetch),
             active2, upd, (top_k, top_e))
 
@@ -318,12 +328,13 @@ def generate_requests(
     rack — the rack-local-fraction-1.0 bit-identity guarantee rests on
     this shared code path.
     """
-    rng, r_gen = jax.random.split(carry.rng)
-    clients, reqs = cl.generate(
-        carry.clients, client_cfg, r_gen,
-        wl.cdf, wl.perm, wl.vlen,
-        carry.offered, carry.write_ratio, cfg.num_servers, carry.now,
-    )
+    with stage("repro.gen"):
+        rng, r_gen = jax.random.split(carry.rng)
+        clients, reqs = cl.generate(
+            carry.clients, client_cfg, r_gen,
+            wl.cdf, wl.perm, wl.vlen,
+            carry.offered, carry.write_ratio, cfg.num_servers, carry.now,
+        )
     return rng, clients, reqs
 
 
@@ -338,15 +349,15 @@ def generate_ingress(
     Every source is already subround-major [R, L], so assembly is a single
     lane-axis concat (client requests + pending server replies +
     controller F-REQs — no per-window transposes of value payloads).
-    Shared by :func:`window_step` and the perf-smoke stage breakdown so
-    the timed stages can never drift from the production input pipeline.
+    Generation and the concat are the ``repro.gen`` stage.
     Returns ``(rng', clients', reqs, sub)``.
     """
     rng, clients, reqs = generate_requests(cfg, client_cfg, wl, carry)
-    sub = jax.tree.map(
-        lambda *xs: jnp.concatenate(xs, axis=1), reqs, carry.pending,
-        carry.fetch,
-    )
+    with stage("repro.gen"):
+        sub = jax.tree.map(
+            lambda *xs: jnp.concatenate(xs, axis=1), reqs, carry.pending,
+            carry.fetch,
+        )
     return rng, clients, reqs, sub
 
 
@@ -394,116 +405,126 @@ def process_window(
         # The whole subround is one fused kernel call (single pallas_call on
         # the kernel backends); orbit value bytes stay out of the scan carry
         # and scatter-install once per window (core.pipeline).
-        policy, outs, intervals = pipeline.window_pipeline(
-            carry.policy, sub,
-            recirc_gbps=c.recirc_gbps, window_us=c.window_us,
-            subrounds=c.subrounds, max_serves=c.max_serves,
-            key_size=key_size,
-        )
+        with stage("repro.switch"):
+            policy, outs, intervals = pipeline.window_pipeline(
+                carry.policy, sub,
+                recirc_gbps=c.recirc_gbps, window_us=c.window_us,
+                subrounds=c.subrounds, max_serves=c.max_serves,
+                key_size=key_size,
+            )
         routes, flags, grids, stats = outs.route, outs.flag, outs.grid, outs.stats
         switch_reply = jnp.zeros((pad_to,), bool)
-        # account orbit-served replies (flatten subround dim into C)
-        r_idx = jnp.arange(c.subrounds, dtype=jnp.float32)[:, None, None]
-        serve_time = (
-            carry.now
-            + (r_idx + 0.5) * window / c.subrounds
-            + (grids.order.astype(jnp.float32) + 1.0) * intervals[:, None, None]
-        )
-        clients = cl.account_switch_served(
-            clients, client_cfg,
-            grids.served.reshape(-1, c.max_serves),
-            grids.req_kidx.reshape(-1, c.max_serves),
-            grids.ts.reshape(-1, c.max_serves),
-            grids.kidx.reshape(-1),
-            serve_time.reshape(-1, c.max_serves),
-        )
-        hits = jnp.sum(stats.n_hit)
-        overflow = jnp.sum(stats.n_overflow) + jnp.sum(stats.n_invalid_fwd)
-        installs = jnp.sum(stats.n_install)
-        crn = jnp.sum(stats.n_crn)
-        rx_sw = jnp.sum(stats.n_served)
+        with stage("repro.account"):
+            # account orbit-served replies (flatten subround dim into C)
+            r_idx = jnp.arange(c.subrounds, dtype=jnp.float32)[:, None, None]
+            serve_time = (
+                carry.now
+                + (r_idx + 0.5) * window / c.subrounds
+                + (grids.order.astype(jnp.float32) + 1.0)
+                * intervals[:, None, None]
+            )
+            clients = cl.account_switch_served(
+                clients, client_cfg,
+                grids.served.reshape(-1, c.max_serves),
+                grids.req_kidx.reshape(-1, c.max_serves),
+                grids.ts.reshape(-1, c.max_serves),
+                grids.kidx.reshape(-1),
+                serve_time.reshape(-1, c.max_serves),
+            )
+            hits = jnp.sum(stats.n_hit)
+            overflow = jnp.sum(stats.n_overflow) + jnp.sum(stats.n_invalid_fwd)
+            installs = jnp.sum(stats.n_install)
+            crn = jnp.sum(stats.n_crn)
+            rx_sw = jnp.sum(stats.n_served)
     elif c.scheme == "netcache":
         def one_subround(st, pk):
             st2, route, flag, srep, n_hit = netcache_step(st, pk)
             return st2, (route, flag, srep, n_hit)
 
-        policy, (routes, flags, sreps, n_hits) = jax.lax.scan(
-            one_subround, carry.policy, sub, unroll=c.subrounds
-        )
+        with stage("repro.switch"):
+            policy, (routes, flags, sreps, n_hits) = jax.lax.scan(
+                one_subround, carry.policy, sub, unroll=c.subrounds
+            )
         switch_reply = sreps.reshape(-1)
-        hits = jnp.sum(n_hits)
-        overflow = jnp.zeros((), jnp.int32)
-        installs = jnp.zeros((), jnp.int32)
-        crn = jnp.zeros((), jnp.int32)
-        # switch-served latency ~ switch pipeline (sub-microsecond + wire)
-        lat = jnp.full((pad_to,), 1.0, jnp.float32) + client_cfg.base_rtt_us
-        bucket = jnp.where(switch_reply, cl.lat_bucket(lat), cl.LAT_BUCKETS)
-        clients = clients._replace(
-            hist_switch=sat_add(clients.hist_switch, cl._bucket_counts(bucket)),
-            rx_switch=sat_add(clients.rx_switch,
-                              jnp.sum(switch_reply.astype(jnp.int32))),
-        )
-        rx_sw = jnp.sum(switch_reply.astype(jnp.int32))
+        with stage("repro.account"):
+            hits = jnp.sum(n_hits)
+            overflow = jnp.zeros((), jnp.int32)
+            installs = jnp.zeros((), jnp.int32)
+            crn = jnp.zeros((), jnp.int32)
+            # switch-served latency ~ switch pipeline (sub-microsecond + wire)
+            lat = jnp.full((pad_to,), 1.0, jnp.float32) + client_cfg.base_rtt_us
+            bucket = jnp.where(switch_reply, cl.lat_bucket(lat), cl.LAT_BUCKETS)
+            clients = clients._replace(
+                hist_switch=sat_add(clients.hist_switch,
+                                    cl._bucket_counts(bucket)),
+                rx_switch=sat_add(clients.rx_switch,
+                                  jnp.sum(switch_reply.astype(jnp.int32))),
+            )
+            rx_sw = jnp.sum(switch_reply.astype(jnp.int32))
     else:  # nocache
         def one_subround(st, pk):
             st2, route, flag = nocache_step(st, pk)
             return st2, (route, flag)
 
-        policy, (routes, flags) = jax.lax.scan(one_subround, carry.policy,
-                                        sub, unroll=c.subrounds)
+        with stage("repro.switch"):
+            policy, (routes, flags) = jax.lax.scan(one_subround, carry.policy,
+                                                   sub, unroll=c.subrounds)
         switch_reply = jnp.zeros((pad_to,), bool)
         hits = overflow = installs = crn = jnp.zeros((), jnp.int32)
         rx_sw = jnp.zeros((), jnp.int32)
 
-    route_flat = routes.reshape(-1)
-    flag_flat = flags.reshape(-1)
-    ing_flat = jax.tree.map(lambda a: a.reshape((pad_to,) + a.shape[2:]), sub)
+    with stage("repro.server"):
+        route_flat = routes.reshape(-1)
+        flag_flat = flags.reshape(-1)
+        ing_flat = jax.tree.map(lambda a: a.reshape((pad_to,) + a.shape[2:]),
+                                sub)
+        to_server = (route_flat == ROUTE_SERVER) & ing_flat.valid
+        servers, sout = server_step(
+            carry.servers, server_cfg, ing_flat, to_server, flag_flat,
+            carry.now,
+        )
 
-    # servers
-    to_server = (route_flat == ROUTE_SERVER) & ing_flat.valid
-    servers, sout = server_step(
-        carry.servers, server_cfg, ing_flat, to_server, flag_flat,
-        carry.now,
-    )
+    with stage("repro.account"):
+        # replies forwarded to clients this window (previous window's server
+        # output routed through the switch)
+        to_client = (route_flat == ROUTE_CLIENT) & ing_flat.valid & ~switch_reply
+        rx_srv_before = clients.rx_server
+        clients = cl.account_server_replies(
+            clients, client_cfg, ing_flat, to_client, carry.now + window
+        )
+        rx_srv = clients.rx_server - rx_srv_before
 
-    # replies forwarded to clients this window (previous window's server
-    # output routed through the switch)
-    to_client = (route_flat == ROUTE_CLIENT) & ing_flat.valid & ~switch_reply
-    rx_srv_before = clients.rx_server
-    clients = cl.account_server_replies(
-        clients, client_cfg, ing_flat, to_client, carry.now + window
-    )
-    rx_srv = clients.rx_server - rx_srv_before
+        # next window's pending: server replies, statically padded to a
+        # subround multiple once, then interleaved into the subround-major
+        # carry layout
+        reply_w, reply_pad = _reply_width(cfg, server_cfg)
+        rep = sout.replies
+        if reply_pad:
+            pad_b = empty_batch(reply_pad, c.value_pad)
+            rep = jax.tree.map(lambda a, p: jnp.concatenate([a, p]), rep, pad_b)
+        pending = interleave(rep, c.subrounds)
 
-    # next window's pending: server replies, statically padded to a subround
-    # multiple once, then interleaved into the subround-major carry layout
-    reply_w, reply_pad = _reply_width(cfg, server_cfg)
-    rep = sout.replies
-    if reply_pad:
-        pad_b = empty_batch(reply_pad, c.value_pad)
-        rep = jax.tree.map(lambda a, p: jnp.concatenate([a, p]), rep, pad_b)
-    pending = interleave(rep, c.subrounds)
-
-    metrics = WindowMetrics(
-        tx=jnp.sum((reqs.valid & (reqs.op != OP_NONE)).astype(jnp.int32)),
-        rx_switch=rx_sw,
-        rx_server=rx_srv,
-        served=sout.served_now,
-        dropped=sout.dropped_now,
-        backlog=sout.backlog,
-        hits=hits,
-        overflow=overflow,
-        installs=installs,
-        crn=crn,
-        mismatches=clients.mismatches,
-        fwd=jnp.sum(to_server.astype(jnp.int32)),
-    )
+        metrics = WindowMetrics(
+            tx=jnp.sum((reqs.valid & (reqs.op != OP_NONE)).astype(jnp.int32)),
+            rx_switch=rx_sw,
+            rx_server=rx_srv,
+            served=sout.served_now,
+            dropped=sout.dropped_now,
+            backlog=sout.backlog,
+            hits=hits,
+            overflow=overflow,
+            installs=installs,
+            crn=crn,
+            mismatches=clients.mismatches,
+            fwd=jnp.sum(to_server.astype(jnp.int32)),
+        )
+        fetch = interleave(empty_batch(c.fetch_lanes, c.value_pad), c.subrounds)
     new_carry = SimCarry(
         policy=policy,
         servers=servers,
         clients=clients,
         pending=pending,
-        fetch=interleave(empty_batch(c.fetch_lanes, c.value_pad), c.subrounds),
+        fetch=fetch,
         rng=rng,
         now=carry.now + window,
         offered=carry.offered,

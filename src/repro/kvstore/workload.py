@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.hashing import hash128_u32_np
+from repro.obs import span
 
 
 class WorkloadArrays(NamedTuple):
@@ -126,11 +127,12 @@ class Workload:
         """Swap the n_hot hottest ranks with the n_hot coldest (paper §5.3:
         'every 10 seconds, the popularity of the 128 coldest items and the
         128 hottest items is swapped')."""
-        p = self._perm_np
-        hot = p[:n_hot].copy()
-        p[:n_hot] = p[-n_hot:]
-        p[-n_hot:] = hot
-        self.perm = jnp.asarray(p)
+        with span("repro.churn"):
+            p = self._perm_np
+            hot = p[:n_hot].copy()
+            p[:n_hot] = p[-n_hot:]
+            p[-n_hot:] = hot
+            self.perm = jnp.asarray(p)
 
     def hottest_keys(self, k: int) -> np.ndarray:
         return self._perm_np[:k].copy()
