@@ -25,6 +25,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.hashing import hash128_u32
 from repro.core.scatter_free import unique_writer
@@ -57,6 +58,16 @@ class ServerConfig(NamedTuple):
     track_popularity: bool = False  # only needed when the controller runs
 
 
+# Store versions are held tile-aligned: key k < 1024 * (num_keys // 1024)
+# at (k // 128, k % 128) of an int32[8 * (num_keys // 1024), 128] block,
+# whose (8, 128) tiles are the flat order, so the scan carry and the
+# scatter share one layout (a flat or unaligned carry makes XLA copy all
+# versions into the scatter's layout and back every window); the last
+# num_keys % 1024 keys sit in a flat tail.
+_LANES = 128
+_TILE = 8 * _LANES
+
+
 class ServerState(NamedTuple):
     # per-server FIFO ring buffers [n_srv, Q]
     op: jnp.ndarray
@@ -70,12 +81,22 @@ class ServerState(NamedTuple):
     qlen: jnp.ndarray     # int32[n_srv]
     front: jnp.ndarray    # int32[n_srv]
     rear: jnp.ndarray     # int32[n_srv]
-    key_version: jnp.ndarray   # int32[num_keys] store versions
+    kv_main: jnp.ndarray  # int32[8 * (num_keys // 1024), 128] store versions
+    kv_tail: jnp.ndarray  # int32[num_keys % 1024] versions of the last keys
     tracker: PopularityTracker  # batched: leading dim n_srv
     # lifetime accumulators: COUNTER_DTYPE via sat_add (wrap-safe, like
     # the switch's Counters)
     served: jnp.ndarray   # uint32[n_srv] cumulative
     dropped: jnp.ndarray  # uint32[n_srv] cumulative
+
+    @property
+    def key_version(self):
+        """int32[..., num_keys] store versions of every key, read-only; a
+        host copy (``jax.device_get``) gives a NumPy array."""
+        main, tail = self.kv_main, self.kv_tail
+        flat = main.reshape(main.shape[:-2] + (-1,))
+        xp = np if isinstance(flat, np.ndarray) else jnp
+        return xp.concatenate([flat, tail], axis=-1)
 
 
 def init_servers(cfg: ServerConfig, num_keys: int) -> ServerState:
@@ -88,7 +109,8 @@ def init_servers(cfg: ServerConfig, num_keys: int) -> ServerState:
         vlen=zi(), ts=jnp.zeros((n, q), jnp.float32),
         qlen=jnp.zeros(n, jnp.int32), front=jnp.zeros(n, jnp.int32),
         rear=jnp.zeros(n, jnp.int32),
-        key_version=jnp.zeros(num_keys, jnp.int32),
+        kv_main=jnp.zeros((8 * (num_keys // _TILE), _LANES), jnp.int32),
+        kv_tail=jnp.zeros(num_keys % _TILE, jnp.int32),
         tracker=tracker,
         served=jnp.zeros(n, COUNTER_DTYPE),
         dropped=jnp.zeros(n, COUNTER_DTYPE),
@@ -162,12 +184,25 @@ def server_step(
     s_vlen, s_ts = g(st.vlen), g(st.ts)
 
     # write versions bump before value generation
-    num_keys = st.key_version.shape[0]
     w_mask = live & (s_op == OP_W_REQ)
     with stage("repro.key_version"):
-        kv = st.key_version.at[
-            jnp.where(w_mask, s_kidx, num_keys).reshape(-1)].add(1, mode='drop')
-        version = kv[s_kidx]                           # [n, cap]
+        # Each part is bumped in place, the main block by (row, lane)
+        # indices (a scatter into its flat view is relaid out again); a
+        # lane that writes no key of a part is sent past that part's end
+        # and dropped.
+        main, tail = st.kv_main, st.kv_tail
+        split, k, wm = main.size, s_kidx.reshape(-1), w_mask.reshape(-1)
+        if split:
+            r = jnp.where(wm & (k < split), k // _LANES, main.shape[0])
+            main = main.at[r, k % _LANES].add(1, mode="drop")
+            kc = jnp.minimum(s_kidx, split - 1)
+            version = main[kc // _LANES, kc % _LANES]
+        if tail.size:
+            tail = tail.at[jnp.where(wm & (k >= split), k - split, tail.size)
+                           ].add(1, mode="drop")
+            from_tail = tail[jnp.clip(s_kidx - split, 0, tail.size - 1)]
+            version = (jnp.where(s_kidx < split, version, from_tail)
+                       if split else from_tail)         # [n, cap]
 
     # reply op + FLAG (fragment count where a value is attached)
     true_vlen = s_vlen                                  # set by client from workload
@@ -223,7 +258,7 @@ def server_step(
     st = st._replace(
         qlen=st.qlen - n_serve,
         front=(st.front + n_serve) % q,
-        key_version=kv,
+        kv_main=main, kv_tail=tail,
         served=sat_add(st.served, served_now),
     )
     return st, ServerStepOut(

@@ -5,7 +5,9 @@ v5e chip, at the widths the paper rack hands it: a subround of the
 rack's 1,344-lane window (336 lanes) against 128 entries with 8-slot
 queues, the server tracker's 5 x 2,048 sketch vmapped over 32 servers,
 and the controller's 2,048 report lanes (32 servers x top-64) with int32
-rows.  Nothing runs: this guards Mosaic lowering at no chip time.
+rows.  One more compiles the servers' window under a 12-point sweep at
+10M keys and reads how XLA lays out their store versions.  Nothing runs:
+this guards Mosaic lowering and that layout at no chip time.
 
 The topology is described inside a module-scoped fixture — never at
 import — so every test worker collects the same tests and only the worker
@@ -13,6 +15,9 @@ that runs this file loads the TPU compiler.  The persistent compilation
 cache is off around these compiles: an entry written for a described chip
 cannot be read back without one.
 """
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -111,3 +116,45 @@ def test_hot_gather_compiles_with_int32_rows(one_chip, pallas, n_ids, n_hot):
         kernels.hot_gather, _spec(one_chip, (n_ids,)),
         _spec(one_chip, (n_hot,)), _spec(one_chip, (n_hot, 1)))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+POINTS, KEYS, WINDOWS = 12, 10_000_000, 4
+RACK_LANES = 1344          # a window's ingress: 768 requests, 320 replies, 256 fetches
+_DUS = re.compile(r"= s32\[([0-9,]*)\]\S* dynamic-update-slice\(")
+
+
+def test_server_versions_update_in_place(one_chip, no_persistent_cache):
+    """The paper rack's servers (32 x FIFO 64, 10 serves a window) under
+    ``vmap`` over a 12-point ladder and ``lax.scan`` over windows, with
+    10M keys of versions in the donated carry: the version bump works in
+    the carry's own layout.  A carry XLA has to copy into the scatter's
+    layout and back every window shows up as temporary buffers of about
+    all points' versions and as ``dynamic-update-slice``s of 10M-wide
+    ``s32`` rows."""
+    from repro.core.types import empty_batch
+    from repro.kvstore.server import ServerConfig, init_servers, server_step
+
+    cfg = ServerConfig(num_servers=32, queue_depth=64, cap_per_window=10,
+                       value_pad=1438, max_frags=1)
+
+    def chunk(st, pkts, to_server, flag, now):
+        def one(st_i):
+            def step(s, x):
+                s, out = server_step(s, cfg, *x)
+                return s, jnp.sum(out.replies.val, dtype=jnp.int32)
+            return jax.lax.scan(step, st_i, (pkts, to_server, flag, now))
+        return jax.vmap(one)(st)
+
+    spec = lambda lead, s: _spec(one_chip, lead + s.shape, s.dtype)
+    st = jax.tree.map(lambda s: spec((POINTS,), s),
+                      jax.eval_shape(lambda: init_servers(cfg, KEYS)))
+    pkts = jax.tree.map(lambda s: spec((WINDOWS,), s),
+                        jax.eval_shape(lambda: empty_batch(RACK_LANES)))
+    compiled = jax.jit(chunk, donate_argnums=(0,)).lower(
+        st, pkts, _spec(one_chip, (WINDOWS, RACK_LANES), bool),
+        _spec(one_chip, (WINDOWS, RACK_LANES)),
+        _spec(one_chip, (WINDOWS,), jnp.float32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < KEYS * 4
+    widths = [math.prod(int(d) for d in m.group(1).split(",") if d)
+              for m in _DUS.finditer(compiled.as_text())]
+    assert not [w for w in widths if w >= KEYS], widths
