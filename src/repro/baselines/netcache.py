@@ -45,31 +45,82 @@ from repro.core.types import (
 )
 
 N_PROBES = 2
+ROW = 128  # a tile row: per-slot tables are stored [T // ROW, ROW]
 
 
 class NetCacheState(NamedTuple):
+    """The switch-memory table of ``T`` slots.
+
+    Per-slot tables are stored tile-aligned, slot ``s`` at ``(s // ROW,
+    s % ROW)``, and the value bytes as one ``[T * limit // ROW, ROW]``
+    block, slot ``s``'s bytes from flat offset ``s * limit`` (``limit``
+    divides ``ROW``).  Under ``vmap`` over sweep points a subround's
+    scatters then update the scan carry in its own layout; a ``[T]`` or
+    ``[T, 64]`` table makes XLA copy or reshape the whole table into the
+    scatter's layout and back.
+    """
     hkeys: jnp.ndarray     # uint32[T, 4]
-    occupied: jnp.ndarray  # bool[T]
-    kidx: jnp.ndarray      # int32[T]
-    valid: jnp.ndarray     # bool[T]
-    val: jnp.ndarray       # uint8[T, value_limit]
-    vlen: jnp.ndarray      # int32[T]
+    occupied: jnp.ndarray  # bool[T // ROW, ROW]
+    kidx: jnp.ndarray      # int32[T // ROW, ROW]
+    valid: jnp.ndarray     # bool[T // ROW, ROW]
+    val: jnp.ndarray       # uint8[T * value_limit // ROW, ROW]
+    vlen: jnp.ndarray      # int32[T // ROW, ROW]
     hits: jnp.ndarray      # uint32[] running hit count (sat_add, wrap-safe)
-    version: jnp.ndarray   # int32[T]
+    version: jnp.ndarray   # int32[T // ROW, ROW]
 
 
 def init_netcache(table_size: int, value_limit: int) -> NetCacheState:
     t = table_size
+    if t % ROW or ROW % value_limit:
+        raise ValueError(f"netcache table of {t} slots and {value_limit}-B values: "
+                         f"{ROW} must divide the slots and be a multiple of the bytes")
+    rows = (t // ROW, ROW)
     return NetCacheState(
         hkeys=jnp.zeros((t, HKEY_LANES), jnp.uint32),
-        occupied=jnp.zeros((t,), bool),
-        kidx=jnp.full((t,), -1, jnp.int32),
-        valid=jnp.zeros((t,), bool),
-        val=jnp.zeros((t, value_limit), jnp.uint8),
-        vlen=jnp.zeros((t,), jnp.int32),
+        occupied=jnp.zeros(rows, bool),
+        kidx=jnp.full(rows, -1, jnp.int32),
+        valid=jnp.zeros(rows, bool),
+        val=jnp.zeros((t * value_limit // ROW, ROW), jnp.uint8),
+        vlen=jnp.zeros(rows, jnp.int32),
         hits=jnp.zeros((), COUNTER_DTYPE),
-        version=jnp.zeros((t,), jnp.int32),
+        version=jnp.zeros(rows, jnp.int32),
     )
+
+
+def _slots(st: NetCacheState) -> int:
+    return st.hkeys.shape[0]
+
+
+def _limit(st: NetCacheState) -> int:
+    """Value bytes a slot holds."""
+    return st.val.size // _slots(st)
+
+
+def _cell(slot: jnp.ndarray):
+    """Slot -> (row, lane) of a per-slot table; an out-of-range slot stays
+    out of range (a dropped update)."""
+    return slot // ROW, slot % ROW
+
+
+def _put_values(val: jnp.ndarray, slot: jnp.ndarray, win: jnp.ndarray,
+                v: jnp.ndarray) -> jnp.ndarray:
+    """Write the ``limit`` bytes ``v`` of each winning lane into its slot
+    (``slot`` in range on every lane; the winners' slots are distinct).
+
+    A row of the value block holds ``ROW // limit`` slots.  Each winning
+    lane adds to its slot's row the difference, modulo 256, between its
+    bytes and the slot's bytes as they stand, and zero to the row's other
+    slots.  Winners whose slots share a row then add into separate bytes,
+    and the whole-row scatter-add works on the block in its own layout
+    with no comparison between lanes."""
+    b, limit = v.shape
+    k = ROW // limit                                  # slots per row
+    row, pos = slot // k, slot % k
+    old = val[row].reshape(b, k, limit)
+    mine = (pos[:, None] == jnp.arange(k))[..., None]
+    delta = jnp.where(mine, v[:, None, :] - old, jnp.uint8(0))  # uint8 wraps
+    return val.at[jnp.where(win, row, val.shape[0])].add(
+        delta.reshape(b, ROW), mode='drop')
 
 
 def _probe_slots(hkey: jnp.ndarray, table_size: int) -> jnp.ndarray:
@@ -82,12 +133,24 @@ def _probe_slots(hkey: jnp.ndarray, table_size: int) -> jnp.ndarray:
 
 def _match(st: NetCacheState, hkey: jnp.ndarray) -> jnp.ndarray:
     """int32[B] slot or -1."""
-    slots = _probe_slots(hkey, st.occupied.shape[0])          # [B, P]
-    eq = jnp.all(st.hkeys[slots] == hkey[:, None, :], axis=-1) & st.occupied[slots]
+    slots = _probe_slots(hkey, _slots(st))                # [B, P]
+    eq = (jnp.all(st.hkeys[slots] == hkey[:, None, :], axis=-1)
+          & st.occupied[_cell(slots)])
     hit = jnp.any(eq, axis=-1)
     which = jnp.argmax(eq, axis=-1)
     slot = jnp.take_along_axis(slots, which[:, None], axis=1)[:, 0]
     return jnp.where(hit, slot, -1)
+
+
+def last_install(slot: jnp.ndarray, install: jnp.ndarray) -> jnp.ndarray:
+    """bool[B]: the installing lanes no later installing lane of the same
+    slot overrides -- the last masked lane per slot in lane order, the
+    order scatter updates apply in (``core.scatter_free.last_writer``).
+    Lanes are compared with each other ([B, B]), not with the table."""
+    lanes = jnp.arange(slot.shape[0])
+    later = ((slot[None, :] == slot[:, None]) & install[None, :]
+             & (lanes[None, :] > lanes[:, None]))
+    return install & ~jnp.any(later, axis=1)
 
 
 def netcache_step(st: NetCacheState, pkts: PacketBatch):
@@ -108,25 +171,28 @@ def netcache_step(st: NetCacheState, pkts: PacketBatch):
     f_rep = valid & (op == OP_F_REP)
     passthru = valid & ((op == OP_CRN_REQ) | (op == OP_F_REQ))
 
-    entry_valid = st.valid[safe] & hit
+    entry_valid = st.valid[_cell(safe)] & hit
     switch_reply = r_req & hit & entry_valid
     n_hit = jnp.sum(switch_reply.astype(jnp.int32))
 
     # writes invalidate, then write-through to the server (FLAG=1 if cached)
     w_cached = w_req & hit
-    t = st.occupied.shape[0]
-    widx = jnp.where(w_cached, slot, t)
+    t = _slots(st)
+    widx = _cell(jnp.where(w_cached, slot, t))
     valid_arr = st.valid.at[widx].set(False, mode='drop')
     version = st.version.at[widx].add(1, mode='drop')
     flag = jnp.where(w_cached, jnp.int32(1), pkts.flag)
 
-    # write/fetch replies refresh the stored value
+    # write/fetch replies refresh the stored value; of several replies to
+    # one slot in a batch the last lane's bytes and length stand
     install = (w_rep | f_rep) & hit & (pkts.flag >= 1)
-    iidx = jnp.where(install, slot, t)
-    limit = st.val.shape[1]
-    valid_arr = valid_arr.at[iidx].set(True, mode='drop')
-    val = st.val.at[iidx].set(pkts.val[:, :limit], mode='drop')
-    vlen = st.vlen.at[iidx].set(jnp.minimum(pkts.vlen, limit), mode='drop')
+    win = last_install(slot, install)
+    islot = jnp.where(win, slot, t)
+    limit = _limit(st)
+    valid_arr = valid_arr.at[_cell(islot)].set(True, mode='drop')
+    vlen = st.vlen.at[_cell(islot)].set(jnp.minimum(pkts.vlen, limit),
+                                         mode='drop')
+    val = _put_values(st.val, safe, win, pkts.val[:, :limit])
 
     route = jnp.full(pkts.width, ROUTE_DROP, jnp.int32)
     to_server = (r_req & ~switch_reply) | w_req | passthru
@@ -160,13 +226,10 @@ def netcache_install(
     """
     from repro.kvstore.store import synth_value_np
 
-    t = st.occupied.shape[0]
-    hk_all = st.hkeys if isinstance(st.hkeys, np.ndarray) else np.asarray(st.hkeys)
-    hkeys, occupied = hk_all.copy(), np.asarray(st.occupied).copy()
-    kidx = np.asarray(st.kidx).copy()
-    valid = np.asarray(st.valid).copy()
-    val = np.asarray(st.val).copy()
-    vlen_arr = np.asarray(st.vlen).copy()
+    t, width = _slots(st), _limit(st)
+    flat = lambda a, *shape: np.array(a).reshape(t, *shape)  # a host copy
+    hkeys, occupied, kidx = flat(st.hkeys, HKEY_LANES), flat(st.occupied), flat(st.kidx)
+    valid, val, vlen_arr = flat(st.valid), flat(st.val, width), flat(st.vlen)
 
     installed = 0
     for k, vl in zip(np.asarray(keys), np.asarray(vlens)):
@@ -182,16 +245,17 @@ def netcache_install(
                 occupied[s] = True
                 kidx[s] = k
                 valid[s] = True
-                v = synth_value_np(int(k), 0, val.shape[1])
-                val[s] = np.where(np.arange(val.shape[1]) < vl, v, 0)
+                v = synth_value_np(int(k), 0, width)
+                val[s] = np.where(np.arange(width) < vl, v, 0)
                 vlen_arr[s] = vl
                 placed = True
                 break
         installed += int(placed)
+    back = lambda a, like: jnp.asarray(a.reshape(np.shape(like)))
     return st._replace(
-        hkeys=jnp.asarray(hkeys), occupied=jnp.asarray(occupied),
-        kidx=jnp.asarray(kidx), valid=jnp.asarray(valid),
-        val=jnp.asarray(val), vlen=jnp.asarray(vlen_arr),
+        hkeys=jnp.asarray(hkeys), occupied=back(occupied, st.occupied),
+        kidx=back(kidx, st.kidx), valid=back(valid, st.valid),
+        val=back(val, st.val), vlen=back(vlen_arr, st.vlen),
     ), installed
 
 

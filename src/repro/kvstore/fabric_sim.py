@@ -642,7 +642,6 @@ class FabricSimulator:
         """Install rack hot sets + the global spine hot set, then warm up."""
         c = self.cfg
         fcfg = self.fcfg
-        warm = False
         if c.scheme == "orbitcache":
             pols, fbs = [], []
             for i in range(fcfg.n_racks):
@@ -654,7 +653,6 @@ class FabricSimulator:
             self.carry = self.carry._replace(
                 racks=self.carry.racks._replace(
                     policy=_tree_stack(pols), fetch=_tree_stack(fbs)))
-            warm = True
         elif c.scheme == "netcache":
             pols = []
             ks = self.wl.hottest_keys(c.netcache_entries)
@@ -668,8 +666,9 @@ class FabricSimulator:
                 racks=self.carry.racks._replace(policy=_tree_stack(pols)))
         self.carry = self.carry._replace(
             spine=preload_spine(self.carry.spine, c, fcfg, self.wl))
-        if warm and warm_windows > 0:
-            # let rack F-REQs reach servers and F-REPs install orbit lines
+        if c.scheme != "nocache" and warm_windows > 0:
+            # let rack F-REQs reach servers and F-REPs install orbit lines;
+            # NetCache racks warm alike, as a rack fleet's preload does
             self.run_windows(warm_windows)
 
     # ------------------------------------------------------------------ run

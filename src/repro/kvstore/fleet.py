@@ -283,8 +283,6 @@ class BatchedRackSimulator:
                                              fetches))
             self.carry = self.carry._replace(
                 policy=_tree_stack(pols), fetch=_tree_stack(fbs))
-            # warm: let F-REQs reach servers and F-REPs install orbit lines
-            self.run_windows(16)
         elif c.scheme == "netcache":
             pols = []
             for i in range(self.n_points):
@@ -297,6 +295,9 @@ class BatchedRackSimulator:
                 )
                 pols.append(st)
             self.carry = self.carry._replace(policy=_tree_stack(pols))
+        # warm: F-REQs reach servers and F-REPs install orbit lines; both
+        # caches start measuring with the same windows behind them
+        self.run_windows(16)
 
     # ------------------------------------------------------------------ run
     def _chunk(self, n: int, wl_axes: WorkloadArrays):
@@ -435,7 +436,7 @@ class BatchedFabricSimulator:
         # host-side table surgery per point, warm-up batched: the warm
         # windows run through the SAME vmapped chunk as the measurement,
         # so no serial fabric step is ever compiled for a sweep
-        warm = any(s.cfg.scheme == "orbitcache" for s in self._sims)
+        warm = any(s.cfg.scheme != "nocache" for s in self._sims)
         for sim in self._sims:
             sim.preload(warm_windows=0)
         self._stack()

@@ -801,14 +801,15 @@ class RackSimulator:
 
     # -------------------------------------------------------------- preload
     def preload(self, keys: np.ndarray) -> None:
-        """Install the hot set before measuring (paper §5.1)."""
+        """Install the hot set before measuring (paper §5.1), then run the
+        16 warm-up windows."""
         c = self.cfg
+        if c.scheme == "nocache":
+            return
         if c.scheme == "orbitcache":
             sw, fetches = self.controller.preload(self.carry.policy, keys)
             self.carry = self.carry._replace(policy=sw)
             self.inject_fetches(fetches)
-            # warm: let F-REQs reach servers and F-REPs install orbit lines
-            self.run_windows(16)
         elif c.scheme == "netcache":
             st, n = netcache_install(
                 self.carry.policy, keys, self.wl.vlen_np[keys],
@@ -817,7 +818,9 @@ class RackSimulator:
             )
             self.carry = self.carry._replace(policy=st)
             self._installed = n
-        # nocache: nothing to do
+        # warm: F-REQs reach servers and F-REPs install orbit lines; both
+        # caches start measuring with the same windows behind them
+        self.run_windows(16)
 
     def inject_fetches(self, fetches: list[tuple[int, int]]) -> None:
         """Queue controller F-REQs for the next window (value fetch via the

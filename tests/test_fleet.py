@@ -113,3 +113,23 @@ def test_batched_rejects_mismatched_points(wl):
         BatchedRackSimulator(CFG, [wl, small])
     with pytest.raises(ValueError, match="sweep points"):
         BatchedRackSimulator(CFG, [wl, wl, wl], offered_rps=(1e6, 2e6))
+
+
+@pytest.mark.parametrize("scheme", ["orbitcache", "netcache"])
+def test_preloads_run_the_warm_windows(wl, scheme):
+    """Both caches' preloads, serial and batched, end with the same 16
+    warm-up windows behind them, so a sweep's first measured window is
+    the 17th whichever switch it runs."""
+    cfg = dataclasses.replace(CFG, scheme=scheme)
+    keys = wl.hottest_keys(64 if scheme == "orbitcache" else 2000)
+    sim = RackSimulator(cfg, wl)
+    sim.preload(keys)
+    bsim = BatchedRackSimulator(cfg, wl, seeds=[cfg.seed])
+    bsim.preload([keys])
+    warm_us = 16 * cfg.window_us
+    assert float(sim.carry.now) == warm_us
+    np.testing.assert_array_equal(np.asarray(bsim.carry.now), [warm_us])
+    assert int(sim.carry.clients.tx) > 0
+    for got, want in zip(jax.tree.leaves(_tree_take(bsim.carry, 0)),
+                         jax.tree.leaves(sim.carry)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -163,7 +163,7 @@ def test_netcache_invariants_over_randomized_trace():
         sim.run_windows(4)
         st = sim.carry.policy
         vlen = np.asarray(st.vlen)
-        limit = st.val.shape[1]
+        limit = cfg.netcache_value_limit
         assert (vlen >= 0).all() and (vlen <= limit).all(), (
             "netcache stored a value beyond its hardware limit")
         hits = int(st.hits)

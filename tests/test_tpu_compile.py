@@ -158,3 +158,49 @@ def test_server_versions_update_in_place(one_chip, no_persistent_cache):
     widths = [math.prod(int(d) for d in m.group(1).split(",") if d)
               for m in _DUS.finditer(compiled.as_text())]
     assert not [w for w in widths if w >= KEYS], widths
+
+
+NC_TABLE, NC_LIMIT = 32768, 64
+_WHOLE = re.compile(r"^\s*(?:ROOT )?%\S+ = \w+\[([0-9,]*)\]\S* "
+                    r"(copy|reshape|transpose|dynamic-update-slice)\(", re.M)
+
+
+def _ladder_chunk(sharding, scheme):
+    """The paper rack's vmapped ladder chunk (12 points, 10M keys, the
+    fleet's own jitted chunk) compiled for a described v5e."""
+    from dataclasses import replace
+
+    from repro.kvstore.fleet import compiled_batched_chunk
+    from repro.kvstore.simulator import (RackConfig, init_carry,
+                                         make_client_config,
+                                         make_server_config)
+    from repro.kvstore.workload import WorkloadArrays
+
+    cfg = RackConfig(scheme=scheme, recirc_gbps=150.0, netcache_table=NC_TABLE,
+                     netcache_value_limit=NC_LIMIT)
+    scfg, ccfg = make_server_config(cfg), make_client_config(cfg)
+    carry = jax.eval_shape(lambda: init_carry(cfg, scfg, ccfg, KEYS, 1e6, 0.0, 0))
+    carry = jax.tree.map(lambda s: _spec(sharding, s.shape, s.dtype, POINTS), carry)
+    wl = WorkloadArrays(cdf=_spec(sharding, (KEYS,), jnp.float32),
+                        perm=_spec(sharding, (KEYS,)), vlen=_spec(sharding, (KEYS,)))
+    chunk = compiled_batched_chunk(replace(cfg, seed=0), scfg, ccfg, 16, WINDOWS,
+                                   WorkloadArrays(None, None, None))
+    return chunk.lower(wl, carry).compile()
+
+
+def test_netcache_tables_update_in_place(one_chip, pallas):
+    """NetCache's per-subround scatters (validity twice, version, length and
+    value bytes) into the 12-point ladder's donated scan carry work in the
+    carry's own layout: no copy, reshape, transpose or
+    ``dynamic-update-slice`` of a whole table over all points, and no more
+    temporary memory than the no-cache chunk's plus less than one value
+    table."""
+    compiled = _ladder_chunk(one_chip, "netcache")
+    tables = {POINTS * NC_TABLE, POINTS * NC_TABLE * NC_LIMIT}
+    whole = [(m.group(2), m.group(1)) for m in _WHOLE.finditer(compiled.as_text())
+             if math.prod(int(d) for d in m.group(1).split(",") if d) in tables]
+    assert not whole, whole
+    base = _ladder_chunk(one_chip, "nocache")
+    extra = (compiled.memory_analysis().temp_size_in_bytes
+             - base.memory_analysis().temp_size_in_bytes)
+    assert extra < POINTS * NC_TABLE * NC_LIMIT, extra
