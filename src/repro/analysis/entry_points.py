@@ -161,10 +161,9 @@ def _controller_chunk() -> EntryPoint:
 def _fleet_window_step() -> EntryPoint:
     from repro.kvstore import fleet
     from repro.kvstore.simulator import tree_stack
-    from repro.kvstore.workload import WorkloadArrays
 
     cfg, wl, scfg, ccfg = _rack_parts()
-    wl_axes = WorkloadArrays(cdf=None, perm=None, vlen=None)  # shared leaves
+    wl_axes = jax.tree.map(lambda _: None, wl.arrays)  # shared leaves
 
     def fn():
         return fleet.compiled_batched_chunk(cfg, scfg, ccfg, wl.cfg.key_size,

@@ -103,7 +103,6 @@ def user_site(eqn) -> str:
 _LIB_FILES = (
     "jax/_src/random.py",            # randint/poisson sample math
     "jax/_src/prng.py",              # key internals
-    "jax/_src/numpy/lax_numpy.py",   # searchsorted's binary-search index math
 )
 
 
@@ -114,10 +113,9 @@ def is_library_internal(eqn) -> bool:
     ``jax/`` are machinery; if a frame from one of the algorithmic
     library files appears before the first non-jax frame, the eqn is
     library code (e.g. the int32 sample math inside
-    ``jax.random.randint`` or ``jnp.searchsorted``'s binary search), not
-    a repro-authored site.  Plain operator arithmetic (``a + b``)
-    dispatches through ``array_methods``/``ufuncs`` only, so
-    user-authored counter math is never classified internal.
+    ``jax.random.randint``), not a repro-authored site.  Plain operator
+    arithmetic (``a + b``) dispatches through ``array_methods``/``ufuncs``
+    only, so user-authored counter math is never classified internal.
     """
     tb = getattr(eqn.source_info, "traceback", None)
     if tb is None:

@@ -33,6 +33,8 @@ from repro.core.types import (
     sat_add,
 )
 
+from .workload import WorkloadArrays
+
 LAT_BUCKETS = 80
 _LAT_BASE_US = 0.25  # bucket 0 lower edge
 
@@ -104,9 +106,7 @@ def generate(
     st: ClientState,
     cfg: ClientConfig,
     rng: jax.Array,
-    cdf: jnp.ndarray,          # workload Zipf CDF
-    perm: jnp.ndarray,         # rank -> kidx
-    vlen_table: jnp.ndarray,   # kidx -> value bytes
+    wl: WorkloadArrays,        # Zipf sampler, rank -> kidx, kidx -> bytes
     offered_per_window: jnp.ndarray,  # float: lambda
     write_ratio: jnp.ndarray,
     num_servers: int,
@@ -139,8 +139,7 @@ def generate(
         return x.reshape((x.shape[0] // r_sub, r_sub) + x.shape[1:]).swapaxes(0, 1)
 
     u = ilv(jax.random.uniform(r2, (b,), jnp.float32))
-    ranks = jnp.searchsorted(cdf, u).astype(jnp.int32)
-    kidx = perm[jnp.clip(ranks, 0, perm.shape[0] - 1)]
+    kidx = wl.perm[jnp.clip(wl.ranks(u), 0, wl.perm.shape[0] - 1)]
     is_write = ilv(jax.random.uniform(r3, (b,), jnp.float32)) < write_ratio
     seq = st.next_seq + lane
     op = jnp.where(is_write, OP_W_REQ, OP_R_REQ)
@@ -151,7 +150,7 @@ def generate(
         hkey=hash128_u32(kidx),
         flag=jnp.zeros((r_sub, lc), jnp.int32),
         kidx=kidx,
-        vlen=vlen_table[kidx],
+        vlen=wl.vlen[kidx],
         client=seq % cfg.num_clients,
         port=jnp.zeros((r_sub, lc), jnp.int32),
         server=server_of_key(kidx, num_servers),
@@ -173,7 +172,7 @@ def generate(
         hkey=hash128_u32(crn_kidx),
         flag=jnp.zeros((r_sub, lcrn), jnp.int32),
         kidx=crn_kidx,
-        vlen=vlen_table[crn_kidx],
+        vlen=wl.vlen[crn_kidx],
         client=crn_seq % cfg.num_clients,
         port=jnp.zeros((r_sub, lcrn), jnp.int32),
         server=server_of_key(crn_kidx, num_servers),

@@ -9,7 +9,7 @@ idle between many small dispatches; :class:`BatchedRackSimulator` instead
 leading rack axis, so a whole sweep advances in lockstep inside a single
 compiled ``lax.scan`` chunk.
 
-Sweep axes that change *data* (offered load, write ratio, Zipf CDF, value
+Sweep axes that change *data* (offered load, write ratio, Zipf table, value
 sizes, RNG seed) batch freely.  Axes that change *shapes or control flow*
 (scheme, cache_entries, num_servers, subrounds, ...) are static: group
 points by RackConfig and run one fleet per group.
@@ -223,22 +223,31 @@ class BatchedRackSimulator:
     def _wl_and_axes(self) -> tuple[WorkloadArrays, WorkloadArrays]:
         """Stack workload leaves only where points differ (else share)."""
         ws = self.workloads
-        same_cdf = all((w.cfg.zipf_alpha, w.cfg.num_keys)
-                       == (ws[0].cfg.zipf_alpha, ws[0].cfg.num_keys)
-                       for w in ws)
+        same_zipf = all((w.cfg.zipf_alpha, w.cfg.num_keys)
+                        == (ws[0].cfg.zipf_alpha, ws[0].cfg.num_keys)
+                        for w in ws)
         same_vlen = all((w.cfg.value_sizes, w.cfg.value_seed, w.cfg.num_keys)
                         == (ws[0].cfg.value_sizes, ws[0].cfg.value_seed,
                             ws[0].cfg.num_keys)
                         for w in ws)
         same_perm = all(w is ws[0] or np.array_equal(w._perm_np, ws[0]._perm_np)
                         for w in ws)
-        cdf = ws[0].cdf if same_cdf else jnp.stack([w.cdf for w in ws])
-        perm = ws[0].perm if same_perm else jnp.stack([w.perm for w in ws])
-        vlen = ws[0].vlen if same_vlen else jnp.stack([w.vlen for w in ws])
-        axes = WorkloadArrays(cdf=None if same_cdf else 0,
-                              perm=None if same_perm else 0,
-                              vlen=None if same_vlen else 0)
-        return WorkloadArrays(cdf=cdf, perm=perm, vlen=vlen), axes
+
+        def leaf(same, get):  # (leaf, its in_axes)
+            if same or get(ws[0]) is None:
+                return get(ws[0]), None
+            return jnp.stack([get(w) for w in ws]), 0
+
+        leaves = dict(table=leaf(same_zipf, lambda w: w.zipf_table),
+                      cdf=leaf(same_zipf, lambda w: w.cdf),
+                      perm=leaf(same_perm, lambda w: w.perm),
+                      vlen=leaf(same_vlen, lambda w: w.vlen))
+        # one static search depth for every point: the deepest one's
+        fine = max(w.zipf_fine_steps for w in ws)
+        return (WorkloadArrays(**{k: v for k, (v, _) in leaves.items()},
+                               fine_steps=fine),
+                WorkloadArrays(**{k: a for k, (_, a) in leaves.items()},
+                               fine_steps=fine))
 
     # -------------------------------------------------------- dynamic knobs
     def _per_point(self, x, dtype=jnp.float32):

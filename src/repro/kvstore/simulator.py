@@ -331,8 +331,7 @@ def generate_requests(
     with stage("repro.gen"):
         rng, r_gen = jax.random.split(carry.rng)
         clients, reqs = cl.generate(
-            carry.clients, client_cfg, r_gen,
-            wl.cdf, wl.perm, wl.vlen,
+            carry.clients, client_cfg, r_gen, wl,
             carry.offered, carry.write_ratio, cfg.num_servers, carry.now,
         )
     return rng, clients, reqs

@@ -17,6 +17,7 @@ import pytest
 from repro.core.types import COUNTER_DTYPE, OP_R_REP, OP_R_REQ, empty_batch
 from repro.kvstore import client as cl
 from repro.kvstore.server import ServerConfig, init_servers, server_step
+from repro.kvstore.workload import WorkloadArrays, zipf_inverse_table
 
 TOP = int(jnp.iinfo(COUNTER_DTYPE).max)
 
@@ -36,11 +37,14 @@ def test_generate_tx_saturates():
     cfg = _client_cfg()
     st = _near_top(cl.init_clients(cfg), tx=TOP - 2)
     nk = 16
+    cdf = np.linspace(1.0 / nk, 1.0, nk, dtype=np.float32)
+    table, fine = zipf_inverse_table(cdf)
     st2, _ = cl.generate(
         st, cfg, jax.random.PRNGKey(0),
-        cdf=jnp.linspace(1.0 / nk, 1.0, nk),
-        perm=jnp.arange(nk, dtype=jnp.int32),
-        vlen_table=jnp.full((nk,), 8, jnp.int32),
+        wl=WorkloadArrays(table=jnp.asarray(table), cdf=jnp.asarray(cdf),
+                          perm=jnp.arange(nk, dtype=jnp.int32),
+                          vlen=jnp.full((nk,), 8, jnp.int32),
+                          fine_steps=fine),
         offered_per_window=jnp.float32(1000.0),   # >> batch: n == batch
         write_ratio=jnp.float32(0.0),
         num_servers=2, now=jnp.float32(0.0))

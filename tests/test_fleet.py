@@ -12,7 +12,7 @@ import pytest
 
 from repro.kvstore.fleet import BatchedRackSimulator, _tree_take
 from repro.kvstore.simulator import RackConfig, RackSimulator
-from repro.kvstore.workload import Workload, WorkloadConfig
+from repro.kvstore.workload import Workload, WorkloadArrays, WorkloadConfig
 
 CFG = RackConfig(scheme="orbitcache", cache_entries=64, num_servers=8,
                  client_batch=256, fetch_lanes=64)
@@ -63,7 +63,8 @@ def test_batched_preload_matches_serial_tables(wl, scheme):
     keys = [w.hottest_keys(64 if scheme == "orbitcache" else 2000)
             for w in points]
     bsim = BatchedRackSimulator(cfg, points)
-    assert bsim._wl_axes.cdf == 0 and bsim._wl_axes.perm is None
+    axes = bsim._wl_axes
+    assert axes.table == 0 and axes.cdf == 0 and axes.perm is None
     bsim.preload(keys)
     for i, w in enumerate(points):
         sim = RackSimulator(dataclasses.replace(cfg, seed=cfg.seed + i), w)
@@ -99,12 +100,16 @@ def test_batched_shares_unchanged_workload_leaves(wl):
     # same point replicated: every leaf shared
     b1 = BatchedRackSimulator(CFG, wl, n_points=4)
     _, axes = b1._wl_and_axes()
-    assert axes == (None, None, None)
-    # skew sweep: only the CDF is stacked
+    assert axes == WorkloadArrays(None, None, None, None, wl.zipf_fine_steps)
+    # skew sweep: only the Zipf table and CDF are stacked, searched to the
+    # deeper of the two points' fine steps
     b2 = BatchedRackSimulator(CFG, [wl, wl2])
     arrs, axes = b2._wl_and_axes()
-    assert axes.cdf == 0 and axes.perm is None and axes.vlen is None
-    assert arrs.cdf.shape == (2, 20_000)
+    assert axes.table == 0 and axes.cdf == 0
+    assert axes.perm is None and axes.vlen is None
+    assert arrs.table.shape == (2, 2**14) and arrs.cdf.shape == (2, 20_000)
+    assert arrs.fine_steps == axes.fine_steps == max(
+        wl.zipf_fine_steps, wl2.zipf_fine_steps)
 
 
 def test_batched_rejects_mismatched_points(wl):

@@ -205,8 +205,7 @@ def _composed_window_step(cfg, server_cfg, client_cfg, key_size, wl, carry):
     c = cfg
     rng, r_gen = jax.random.split(carry.rng)
     clients, reqs = cl.generate(
-        carry.clients, client_cfg, r_gen,
-        wl.cdf, wl.perm, wl.vlen,
+        carry.clients, client_cfg, r_gen, wl,
         carry.offered, carry.write_ratio, c.num_servers, carry.now,
     )
     sub = jax.tree.map(

@@ -15,6 +15,7 @@ that runs this file loads the TPU compiler.  The persistent compilation
 cache is off around these compiles: an entry written for a described chip
 cannot be read back without one.
 """
+import functools
 import math
 import re
 
@@ -163,29 +164,56 @@ def test_server_versions_update_in_place(one_chip, no_persistent_cache):
 NC_TABLE, NC_LIMIT = 32768, 64
 _WHOLE = re.compile(r"^\s*(?:ROOT )?%\S+ = \w+\[([0-9,]*)\]\S* "
                     r"(copy|reshape|transpose|dynamic-update-slice)\(", re.M)
+_WHILE = re.compile(r" while\([^\n]*?op_name=\"([^\"]*)\"")
+
+
+@functools.cache
+def _paper_workload():
+    """The paper's workload (10M keys, Zipf 0.99), built once."""
+    from repro.kvstore.workload import Workload, WorkloadConfig
+    return Workload(WorkloadConfig(num_keys=KEYS, zipf_alpha=0.99))
 
 
 def _ladder_chunk(sharding, scheme):
-    """The paper rack's vmapped ladder chunk (12 points, 10M keys, the
-    fleet's own jitted chunk) compiled for a described v5e."""
+    """The paper rack's vmapped ladder chunk (12 points sharing the paper
+    workload's arrays, the fleet's own jitted chunk) compiled for a
+    described v5e."""
     from dataclasses import replace
 
     from repro.kvstore.fleet import compiled_batched_chunk
     from repro.kvstore.simulator import (RackConfig, init_carry,
                                          make_client_config,
                                          make_server_config)
-    from repro.kvstore.workload import WorkloadArrays
 
     cfg = RackConfig(scheme=scheme, recirc_gbps=150.0, netcache_table=NC_TABLE,
                      netcache_value_limit=NC_LIMIT)
     scfg, ccfg = make_server_config(cfg), make_client_config(cfg)
     carry = jax.eval_shape(lambda: init_carry(cfg, scfg, ccfg, KEYS, 1e6, 0.0, 0))
     carry = jax.tree.map(lambda s: _spec(sharding, s.shape, s.dtype, POINTS), carry)
-    wl = WorkloadArrays(cdf=_spec(sharding, (KEYS,), jnp.float32),
-                        perm=_spec(sharding, (KEYS,)), vlen=_spec(sharding, (KEYS,)))
+    arrays = _paper_workload().arrays
+    wl = jax.tree.map(lambda a: _spec(sharding, a.shape, a.dtype), arrays)
     chunk = compiled_batched_chunk(replace(cfg, seed=0), scfg, ccfg, 16, WINDOWS,
-                                   WorkloadArrays(None, None, None))
+                                   jax.tree.map(lambda _: None, arrays))
     return chunk.lower(wl, carry).compile()
+
+
+def test_ladder_chunk_draws_zipf_ranks_in_one_gather(one_chip, pallas):
+    """At the paper's 10M keys each Zipf rank is one gather from the
+    inverse table: the chunk's workload arguments hold no float32 CDF, the
+    compiled program holds no 10M-entry float32 array, and client
+    generation (``repro.gen``) runs no loop but ``jax.random.poisson``'s,
+    where it once ran a 24-trip binary search of the CDF."""
+    wl = _paper_workload()
+    assert (wl.zipf_bits, wl.zipf_fine_steps) == (23, 0)
+    leaves = jax.tree.leaves(wl.arrays)
+    assert [(a.shape, a.dtype) for a in leaves] == [
+        ((2**23,), jnp.int32), ((KEYS,), jnp.int32), ((KEYS,), jnp.int32)]
+    text = _ladder_chunk(one_chip, "nocache").as_text()
+    assert f"f32[{KEYS}]" not in text
+    loops = _WHILE.findall(text)
+    assert loops, "no while loop found: the chunk's window scan is one"
+    gen = [n for n in loops if "/repro.gen/" in n and "_poisson" not in n]
+    assert not gen, gen
 
 
 def test_netcache_tables_update_in_place(one_chip, pallas):
