@@ -16,12 +16,9 @@ Scale notes vs the paper's testbed (documented deviations):
 from __future__ import annotations
 
 import time
-from dataclasses import replace
 
-import numpy as np
-
-from repro.kvstore.fleet import BatchedRackSimulator
-from repro.kvstore.simulator import RackConfig, RackSimulator
+from repro.kvstore.fleet import BatchedRackSimulator, RackSimulator
+from repro.kvstore.simulator import RackConfig
 from repro.kvstore.workload import Workload, WorkloadConfig
 
 NUM_KEYS = 10_000_000   # paper §5.1: 10M key-value pairs
@@ -81,8 +78,8 @@ def knee_throughput_batched(bsim: BatchedRackSimulator, loads=DEFAULT_LOADS,
                             seconds: float = 0.03, loss_tol: float = 0.02,
                             srv_drop_tol: float = 0.05):
     """Ascending staircase over a fleet: every point climbs the load ladder
-    simultaneously (same methodology as ``knee_throughput``, one batched
-    run per rung instead of one serial run per point per rung).
+    simultaneously (one batched run per rung; the methodology is
+    ``knee_throughput``'s).
 
     Returns one ``(knee_rps, rows)`` per sweep point.
     """
@@ -123,13 +120,11 @@ def knee_throughput(sim: RackSimulator, loads=DEFAULT_LOADS,
     arrivals.  The per-server criterion is the point: one saturated
     hot-key server is the failure mode in-network caching exists to fix,
     and it barely moves *total* loss (it owns only a few % of traffic)
-    while its latency/drops explode — the paper's Fig. 11 knee."""
-    rows = []
-    for rps in loads:
-        sim.set_offered(rps)
-        sim.reset_stats()
-        rows.append(_row(sim.run(seconds)))
-    return _knee_of(rows, loss_tol, srv_drop_tol), rows
+    while its latency/drops explode — the paper's Fig. 11 knee.  The
+    staircase is :func:`knee_throughput_batched` on the view's fleet."""
+    [knee] = knee_throughput_batched(sim.fleet, loads, seconds, loss_tol,
+                                     srv_drop_tol)
+    return knee
 
 
 def workload(alpha=0.99, write_ratio=0.0, value_sizes=((64, 0.82), (1024, 0.18)),

@@ -7,10 +7,9 @@ from __future__ import annotations
 
 import numpy as np
 
-from repro.kvstore.simulator import RackConfig, RackSimulator
 from repro.kvstore.workload import Workload, WorkloadConfig, production_workload
 
-from .common import (DEFAULT_LOADS, NUM_KEYS, RECIRC_GBPS, emit,
+from .common import (DEFAULT_LOADS, NUM_KEYS, emit,
                      knee_throughput, knee_throughput_batched,
                      knee_throughput_parallel, make_batched_sim, make_sim,
                      workload)
@@ -201,6 +200,7 @@ def fig18_dynamic(quick=False):
     for phase in range(3):
         if phase:
             wl.hot_in_swap(128)
+            sim.refresh_workloads()
         res = sim.run(phase_s, controller_period_s=period)
         rx = res.traces["rx_switch"] + res.traces["rx_server"]
         n = len(rx) // 4

@@ -2,8 +2,9 @@
 
 A small rack runs the same total work two ways:
 
-  serial     N independent RackSimulator sweeps, one after another
-             (they share one compiled chunk — seeds are host-side);
+  serial     N one-point fleets (``RackSimulator`` views), one after
+             another (they share one compiled chunk — seeds are
+             host-side);
   batched    one N-point BatchedRackSimulator fleet (vmapped scan).
 
 Both paths are warmed first (compile excluded from the timed region,
@@ -50,8 +51,8 @@ import numpy as np  # noqa: E402
 
 from repro import kernels  # noqa: E402
 from repro.launch.compile_cache import configure_compile_cache  # noqa: E402
-from repro.kvstore.fleet import BatchedRackSimulator  # noqa: E402
-from repro.kvstore.simulator import RackConfig, RackSimulator  # noqa: E402
+from repro.kvstore.fleet import BatchedRackSimulator, RackSimulator  # noqa: E402
+from repro.kvstore.simulator import RackConfig  # noqa: E402
 from repro.kvstore.workload import Workload, WorkloadConfig  # noqa: E402
 
 REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))

@@ -15,7 +15,8 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-from repro.kvstore.simulator import RackConfig, RackSimulator
+from repro.kvstore.fleet import RackSimulator
+from repro.kvstore.simulator import RackConfig
 from repro.kvstore.workload import Workload, WorkloadConfig
 
 
@@ -28,6 +29,7 @@ def main():
     for phase in range(3):
         if phase:
             wl.hot_in_swap(128)   # all cached keys suddenly cold
+            sim.refresh_workloads()
             print(f"-- phase {phase}: hot set swapped "
                   "(every cache entry is now wrong)")
         res = sim.run(0.15, controller_period_s=0.03)

@@ -9,7 +9,8 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-from repro.kvstore.simulator import RackConfig, RackSimulator
+from repro.kvstore.fleet import RackSimulator
+from repro.kvstore.simulator import RackConfig
 from repro.kvstore.workload import Workload, WorkloadConfig
 
 

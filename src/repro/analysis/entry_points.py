@@ -13,13 +13,15 @@ one compiled surface — same code paths, minimal geometry — and exposes:
     differ ONLY in the documented traced axis (fresh carries each call —
     donation invalidates the previous one).
 
-The kernel-backend expectation is a measured architectural constant, not
-a tolerance: the fused subround is ONE ``pallas_call``; the controller
-chunk adds the server cms track kernel and the three hot-gather uses of
-the traced report/merge path (5 total); a fabric window runs rack + spine
-subround kernels (2 — no controller, so no tracking); the fabric
-controller chunk runs both tiers' subrounds, the rack-server cms track,
-and both tiers' hot-gather triples (9).
+The chunk entry points are the fleets' own compiled chunks, mapped over
+two sweep points as the chip runs them; a vmapped ``pallas_call`` is still
+one equation.  The kernel-backend expectation is a measured architectural
+constant, not a tolerance: the fused subround is ONE ``pallas_call``; the
+controller chunk adds the server cms track kernel and the three
+hot-gather uses of the traced report/merge path (5 total); a fabric chunk
+runs rack + spine subround kernels (2 — no controller, so no tracking);
+the fabric controller chunk runs both tiers' subrounds, the rack-server
+cms track, and both tiers' hot-gather triples (9).
 """
 from __future__ import annotations
 
@@ -89,6 +91,12 @@ def _rack_carry(cfg, scfg, ccfg, seed=0):
                           wl.cfg.offered_rps, wl.cfg.write_ratio, seed)
 
 
+def _fleet_carry(cfg, scfg, ccfg, points=2):
+    from repro.kvstore.simulator import tree_stack
+    return tree_stack([_rack_carry(cfg, scfg, ccfg, seed=i)
+                       for i in range(points)])
+
+
 def _ctrl_cfg():
     from repro.core.controller import ControllerConfig
     return ControllerConfig(active_size=8, max_size=8, k_report=4)
@@ -132,24 +140,26 @@ def _window_pipeline() -> EntryPoint:
 
 
 def _controller_chunk() -> EntryPoint:
-    from repro.kvstore import simulator as sim
+    from repro.kvstore import fleet
 
     cfg, wl, scfg, ccfg = _rack_parts(track_popularity=True)
     ctrl = _ctrl_cfg()
+    wl_axes = jax.tree.map(lambda _: None, wl.arrays)  # shared leaves
 
     def fn():
-        return sim.compiled_controller_chunk(
-            cfg, ctrl, scfg, ccfg, wl.cfg.key_size, period_w=2, n_periods=1)
+        return fleet.compiled_batched_controller_chunk(
+            cfg, ctrl, scfg, ccfg, wl.cfg.key_size, period_w=2, n_periods=1,
+            wl_axes=wl_axes)
 
     def args(active=8):
-        return (wl.arrays, _rack_carry(cfg, scfg, ccfg),
-                jnp.asarray(active, jnp.int32))
+        return (wl.arrays, _fleet_carry(cfg, scfg, ccfg),
+                jnp.full((2,), active, jnp.int32))
 
     def mk():
         return jax.make_jaxpr(fn())(*args())
 
     return EntryPoint(
-        "compiled_controller_chunk", mk,
+        "fleet.controller_chunk", mk,
         # fused subround + server cms track + 3x hot_gather (report/merge)
         expected_pallas={"ref": 0, "kernel": 5},
         donation=lambda: (fn(), args()),
@@ -160,7 +170,6 @@ def _controller_chunk() -> EntryPoint:
 
 def _fleet_window_step() -> EntryPoint:
     from repro.kvstore import fleet
-    from repro.kvstore.simulator import tree_stack
 
     cfg, wl, scfg, ccfg = _rack_parts()
     wl_axes = jax.tree.map(lambda _: None, wl.arrays)  # shared leaves
@@ -170,8 +179,7 @@ def _fleet_window_step() -> EntryPoint:
                                             2, wl_axes)
 
     def args(offered=None):
-        carry = tree_stack([_rack_carry(cfg, scfg, ccfg, seed=i)
-                            for i in range(2)])
+        carry = _fleet_carry(cfg, scfg, ccfg)
         if offered is not None:
             carry = carry._replace(
                 offered=jnp.full_like(carry.offered, offered))
@@ -197,30 +205,30 @@ def _fabric_parts(**kw):
     return fs, cfg, fcfg, wl, scfg, ccfg
 
 
-def _fabric_carry(fs, cfg, fcfg):
-    return fs.FabricSimulator(cfg, fcfg, _workload()).carry
+def _fabric_carry(cfg, fcfg, local_frac=None):
+    """A two-point fabric fleet's carry (rack leaves [2, R, ...])."""
+    from repro.kvstore.fleet import BatchedFabricSimulator
+    carry = BatchedFabricSimulator(cfg, fcfg, _workload(), n_points=2).carry
+    if local_frac is not None:
+        carry = carry._replace(local_frac=jnp.full((2,), local_frac,
+                                                   jnp.float32))
+    return carry
 
 
-def _fabric_window_step() -> EntryPoint:
+def _fabric_chunk() -> EntryPoint:
     fs, cfg, fcfg, wl, scfg, ccfg = _fabric_parts()
-
-    def mk():
-        return jax.make_jaxpr(
-            lambda w, c: fs.fabric_window_step(cfg, fcfg, scfg, ccfg,
-                                               wl.cfg.key_size, w, c)
-        )(wl.arrays, _fabric_carry(fs, cfg, fcfg))
 
     def fn():
         return fs.fabric_chunk(cfg, fcfg, scfg, ccfg, wl.cfg.key_size, 2)
 
     def args(local_frac=None):
-        carry = _fabric_carry(fs, cfg, fcfg)
-        if local_frac is not None:
-            carry = carry._replace(local_frac=jnp.float32(local_frac))
-        return (wl.arrays, carry)
+        return (wl.arrays, _fabric_carry(cfg, fcfg, local_frac))
+
+    def mk():
+        return jax.make_jaxpr(fn())(*args())
 
     return EntryPoint(
-        "fabric_window_step", mk,
+        "fabric_chunk", mk,
         # rack-tier + spine-tier fused subround kernels (no controller,
         # so the server cms track kernel is off)
         expected_pallas={"ref": 0, "kernel": 2},
@@ -240,12 +248,9 @@ def _fabric_controller_chunk() -> EntryPoint:
             period_w=2, n_periods=1)
 
     def args(local_frac=None):
-        carry = _fabric_carry(fs, cfg, fcfg)
-        if local_frac is not None:
-            carry = carry._replace(local_frac=jnp.float32(local_frac))
-        ra = jnp.full((fcfg.n_racks,), 8, jnp.int32)
-        sa = jnp.asarray(8, jnp.int32)
-        return (wl.arrays, carry, ra, sa)
+        ra = jnp.full((2, fcfg.n_racks), 8, jnp.int32)
+        sa = jnp.full((2,), 8, jnp.int32)
+        return (wl.arrays, _fabric_carry(cfg, fcfg, local_frac), ra, sa)
 
     def mk():
         return jax.make_jaxpr(fn())(*args())
@@ -266,7 +271,7 @@ _BUILDERS = (
     _window_pipeline,
     _controller_chunk,
     _fleet_window_step,
-    _fabric_window_step,
+    _fabric_chunk,
     _fabric_controller_chunk,
 )
 

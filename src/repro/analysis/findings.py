@@ -19,7 +19,7 @@ class Severity:
 class Finding:
     rule: str            # registry name, e.g. "no-scatter"
     severity: str        # Severity.ERROR | Severity.WARNING
-    entry: str           # entry-point name, e.g. "compiled_controller_chunk"
+    entry: str           # entry-point name, e.g. "fleet.controller_chunk"
     message: str         # human-readable statement of the violation
     op: str = ""         # primitive / HLO opcode involved
     path: str = ""       # source path into the jaxpr, e.g. "pjit/scan[1]/eqn[42]"

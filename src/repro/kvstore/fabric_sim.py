@@ -47,15 +47,18 @@ Deliberate simplifications (documented, metrics-visible):
 
 With ``local_frac == 1.0`` no lane ever crosses the fabric and each
 rack's full state evolution (policy, servers, clients, RNG) is
-bit-identical to R independent :class:`simulator.RackSimulator` /
-:class:`fleet.BatchedRackSimulator` racks — regression-tested in
+bit-identical to R independent racks of a
+:class:`fleet.BatchedRackSimulator` — regression-tested in
 ``tests/test_fabric.py``.
+
+This module holds the fabric's config, carry, step functions, chunk
+builders and results; ``fleet.BatchedFabricSimulator`` runs them.
 """
 from __future__ import annotations
 
 import functools
 from dataclasses import dataclass, field, replace
-from typing import Any, NamedTuple, Sequence
+from typing import Any, NamedTuple
 
 import numpy as np
 
@@ -66,11 +69,7 @@ from repro.baselines.netcache import init_netcache, netcache_install, netcache_s
 from repro.baselines.nocache import nocache_step
 from repro.core import fabric as fb
 from repro.core import pipeline
-from repro.core.controller import (
-    CacheController,
-    ControllerConfig,
-    controller_step,
-)
+from repro.core.controller import ControllerConfig, controller_step
 from repro.core.hashing import hash128_u32, hash128_u32_np, server_of_key
 from repro.core.types import (
     COUNTER_DTYPE,
@@ -88,15 +87,11 @@ from .simulator import (
     RackConfig,
     SimCarry,
     SimResult,
-    build_fetch_batch,
     controller_window_apply,
-    init_carry,
-    make_client_config,
-    make_server_config,
-    process_window,
     generate_requests,
-    tree_stack as _tree_stack,
-    tree_take as _tree_take,
+    init_carry,
+    process_window,
+    tree_stack,
 )
 from .workload import Workload, WorkloadArrays
 
@@ -159,8 +154,30 @@ def init_spine_policy(cfg: RackConfig, fcfg: FabricConfig):
     raise ValueError(f"unknown spine scheme {fcfg.spine_scheme!r}")
 
 
+def init_fabric_carry(cfg: RackConfig, fcfg: FabricConfig, server_cfg,
+                      client_cfg: cl.ClientConfig, num_keys: int,
+                      offered_rps: float, write_ratio: float,
+                      local_frac: float, seed: int) -> FabricCarry:
+    """One fabric's carry: rack ``r`` is seeded ``seed + r`` and the
+    homing draws ``seed + 0x0FAB``, so every rack's RNG stream is a
+    standalone rack's."""
+    racks = tree_stack([
+        init_carry(cfg, server_cfg, client_cfg, num_keys, offered_rps,
+                   write_ratio, seed + r)
+        for r in range(fcfg.n_racks)
+    ])
+    return FabricCarry(
+        racks=racks,
+        spine=init_spine_policy(cfg, fcfg),
+        spine_clients=cl.init_clients(client_cfg),
+        fabric_rng=jax.random.PRNGKey(seed + 0x0FAB),
+        local_frac=jnp.float32(local_frac),
+        spine_drops=jnp.zeros((), COUNTER_DTYPE),
+    )
+
+
 # ---------------------------------------------------------------------------
-# the fabric window step (pure; shared by serial and batched simulators)
+# the fabric window step (pure; the fabric fleet vmaps it over points)
 # ---------------------------------------------------------------------------
 def fabric_window_step(
     cfg: RackConfig,
@@ -376,26 +393,26 @@ def fabric_controller_chunk(cfg: RackConfig, fcfg: FabricConfig,
                             ctrl_cfg: ControllerConfig,
                             spine_ctrl_cfg: ControllerConfig,
                             server_cfg, client_cfg, key_size: int,
-                            period_w: int, n_periods: int,
-                            vmapped: bool = False):
-    """Jitted fabric chunk of ``n_periods`` control-plane periods.
+                            period_w: int, n_periods: int):
+    """Jitted fabric chunk of ``n_periods`` control-plane periods, mapped
+    over a leading point axis.
 
-    Period structure mirrors ``simulator.compiled_controller_chunk``:
+    Period structure mirrors ``simulator.controller_chunk_body``:
     ``period_w`` fabric windows, then :func:`fabric_controller_apply` —
-    all inside one compiled scan, with the per-rack and spine
-    ``active_size`` scalars carried alongside the fabric carry.
+    all inside one compiled scan, with the per-rack (``[N, R]``) and
+    spine (``[N]``) active sizes carried alongside the fabric carry.
     """
     from repro.kernels import kernel_backend
     return _fabric_controller_chunk(
         replace(cfg, seed=0), replace(fcfg, local_frac=0.0), ctrl_cfg,
         spine_ctrl_cfg, server_cfg, client_cfg, key_size, period_w,
-        n_periods, kernel_backend(), vmapped)
+        n_periods, kernel_backend())
 
 
 @functools.lru_cache(maxsize=None)
 def _fabric_controller_chunk(cfg, fcfg, ctrl_cfg, spine_ctrl_cfg, server_cfg,
                              client_cfg, key_size, period_w, n_periods,
-                             kernel_backend, vmapped):
+                             kernel_backend):
     def one(wl: WorkloadArrays, carry_i, ra_i, sa_i):
         def step(c, x):
             return fabric_window_step(cfg, fcfg, server_cfg, client_cfg,
@@ -416,42 +433,38 @@ def _fabric_controller_chunk(cfg, fcfg, ctrl_cfg, spine_ctrl_cfg, server_cfg,
 
     def body(wl: WorkloadArrays, carry: FabricCarry, rack_active,
              spine_active):
-        if vmapped:
-            return jax.vmap(one, in_axes=(None, 0, 0, 0))(
-                wl, carry, rack_active, spine_active)
-        return one(wl, carry, rack_active, spine_active)
+        return jax.vmap(one, in_axes=(None, 0, 0, 0))(
+            wl, carry, rack_active, spine_active)
 
     return jax.jit(body, donate_argnums=(1,))
 
 
 def fabric_chunk(cfg: RackConfig, fcfg: FabricConfig, server_cfg, client_cfg,
-                 key_size: int, n: int, vmapped: bool = False):
+                 key_size: int, n: int):
     """Jitted ``n``-window fabric chunk (donated carry, shared per config).
 
-    With ``vmapped`` the same scan body maps over a leading sweep axis on
-    every carry leaf (``fleet.BatchedFabricSimulator``).  ``seed`` and
-    ``local_frac`` are normalized out of the cache key: the seed is
-    host-side only and the locality fraction is a dynamic carry scalar —
-    fabrics differing only in those share one compilation.
+    The scan body maps over a leading point axis on every carry leaf
+    (``fleet.BatchedFabricSimulator``).  ``seed`` and ``local_frac`` are
+    normalized out of the cache key: the seed is host-side only and the
+    locality fraction is a dynamic carry scalar — fabrics differing only
+    in those share one compilation.
     """
     from repro.kernels import kernel_backend
     return _fabric_chunk(replace(cfg, seed=0), replace(fcfg, local_frac=0.0),
                          server_cfg, client_cfg, key_size, n,
-                         kernel_backend(), vmapped)
+                         kernel_backend())
 
 
 @functools.lru_cache(maxsize=None)
 def _fabric_chunk(cfg, fcfg, server_cfg, client_cfg, key_size, n,
-                  kernel_backend, vmapped):
+                  kernel_backend):
     def body(wl: WorkloadArrays, carry: FabricCarry):
         def one(carry_i):
             def step(c, x):
                 return fabric_window_step(cfg, fcfg, server_cfg, client_cfg,
                                           key_size, wl, c, x)
             return jax.lax.scan(step, carry_i, None, length=n)
-        if vmapped:
-            return jax.vmap(one)(carry)
-        return one(carry)
+        return jax.vmap(one)(carry)
 
     return jax.jit(body, donate_argnums=(1,))
 
@@ -535,7 +548,7 @@ def preload_spine(policy, cfg: RackConfig, fcfg: FabricConfig,
 
 
 # ---------------------------------------------------------------------------
-# host-side drivers
+# results
 # ---------------------------------------------------------------------------
 @dataclass
 class FabricResult:
@@ -562,180 +575,3 @@ class FabricResult:
         srv = self.spine["served"]
         b = int(len(rem) * burn_frac)
         return float(srv[b:].sum() / max(rem[b:].sum(), 1))
-
-
-class FabricSimulator:
-    """R racks + one spine switch advancing in lockstep."""
-
-    def __init__(self, cfg: RackConfig, fcfg: FabricConfig, wl: Workload,
-                 seeds: Sequence[int] | None = None):
-        if fcfg.spine_lanes % cfg.subrounds or fcfg.fwd_lanes % cfg.subrounds:
-            raise ValueError(
-                f"spine_lanes ({fcfg.spine_lanes}) and fwd_lanes "
-                f"({fcfg.fwd_lanes}) must be multiples of subrounds "
-                f"({cfg.subrounds})")
-        self.cfg = cfg
-        self.fcfg = fcfg
-        self.wl = wl
-        self.server_cfg = make_server_config(cfg)
-        self.client_cfg = make_client_config(cfg)
-        self.key_size = wl.cfg.key_size
-        r = fcfg.n_racks
-        seeds = (list(seeds) if seeds is not None
-                 else [cfg.seed + i for i in range(r)])
-        if len(seeds) != r:
-            raise ValueError(f"need {r} seeds, got {len(seeds)}")
-        self.controllers = [
-            CacheController(ControllerConfig(
-                active_size=cfg.cache_entries, max_size=cfg.cache_entries))
-            for _ in range(r)
-        ]
-        self.spine_controller = CacheController(ControllerConfig(
-            active_size=fcfg.spine_cache_entries,
-            max_size=fcfg.spine_cache_entries,
-            k_report=fcfg.spine_k_report))
-        racks = _tree_stack([
-            init_carry(cfg, self.server_cfg, self.client_cfg,
-                       wl.cfg.num_keys, wl.cfg.offered_rps,
-                       wl.cfg.write_ratio, seeds[i])
-            for i in range(r)
-        ])
-        self.carry = FabricCarry(
-            racks=racks,
-            spine=init_spine_policy(cfg, fcfg),
-            spine_clients=cl.init_clients(self.client_cfg),
-            fabric_rng=jax.random.PRNGKey(cfg.seed + 0x0FAB),
-            local_frac=jnp.float32(fcfg.local_frac),
-            spine_drops=jnp.zeros((), COUNTER_DTYPE),
-        )
-
-    # -- dynamic knobs (no recompilation) ------------------------------------
-    def set_local_frac(self, frac: float) -> None:
-        self.carry = self.carry._replace(local_frac=jnp.float32(frac))
-
-    def set_offered(self, rps: float) -> None:
-        lam = jnp.full((self.fcfg.n_racks,),
-                       rps * self.cfg.window_us * 1e-6, jnp.float32)
-        self.carry = self.carry._replace(
-            racks=self.carry.racks._replace(offered=lam))
-
-    def reset_stats(self) -> None:
-        fresh = cl.init_clients(self.client_cfg)
-        stacked = jax.tree.map(
-            lambda x: jnp.stack([x] * self.fcfg.n_racks), fresh)
-        racks = self.carry.racks
-        self.carry = self.carry._replace(
-            racks=racks._replace(clients=stacked._replace(
-                next_seq=racks.clients.next_seq,
-                crn_kidx=racks.clients.crn_kidx,
-                crn_n=racks.clients.crn_n,
-            )),
-            spine_clients=fresh._replace(
-                next_seq=self.carry.spine_clients.next_seq,
-                crn_kidx=self.carry.spine_clients.crn_kidx,
-                crn_n=self.carry.spine_clients.crn_n,
-            ),
-        )
-
-    # ------------------------------------------------------------- preload
-    def preload(self, warm_windows: int = 16) -> None:
-        """Install rack hot sets + the global spine hot set, then warm up."""
-        c = self.cfg
-        fcfg = self.fcfg
-        if c.scheme == "orbitcache":
-            pols, fbs = [], []
-            for i in range(fcfg.n_racks):
-                pol, fetches = self.controllers[i].preload(
-                    _tree_take(self.carry.racks.policy, i),
-                    self.wl.hottest_keys(c.cache_entries))
-                pols.append(pol)
-                fbs.append(build_fetch_batch(c, self.wl.vlen, fetches))
-            self.carry = self.carry._replace(
-                racks=self.carry.racks._replace(
-                    policy=_tree_stack(pols), fetch=_tree_stack(fbs)))
-        elif c.scheme == "netcache":
-            pols = []
-            ks = self.wl.hottest_keys(c.netcache_entries)
-            for i in range(fcfg.n_racks):
-                st, _ = netcache_install(
-                    _tree_take(self.carry.racks.policy, i), ks,
-                    self.wl.vlen_np[ks], key_size=self.key_size,
-                    value_limit=c.netcache_value_limit)
-                pols.append(st)
-            self.carry = self.carry._replace(
-                racks=self.carry.racks._replace(policy=_tree_stack(pols)))
-        self.carry = self.carry._replace(
-            spine=preload_spine(self.carry.spine, c, fcfg, self.wl))
-        if c.scheme != "nocache" and warm_windows > 0:
-            # let rack F-REQs reach servers and F-REPs install orbit lines;
-            # NetCache racks warm alike, as a rack fleet's preload does
-            self.run_windows(warm_windows)
-
-    # ------------------------------------------------------------------ run
-    def _chunk(self, n: int):
-        return fabric_chunk(self.cfg, self.fcfg, self.server_cfg,
-                            self.client_cfg, self.key_size, n)
-
-    def run_windows(self, n: int) -> dict[str, np.ndarray]:
-        """Advance the fabric ``n`` windows.  Rack traces are [n, R, ...]."""
-        carry, ys = self._chunk(n)(self.wl.arrays, self.carry)
-        self.carry = carry
-        return fabric_metrics_dict(ys)
-
-    def run_periods(self, n_periods: int, period_w: int) -> dict[str, np.ndarray]:
-        """Advance ``n_periods`` control-plane periods of ``period_w``
-        windows: per-rack ToR controllers AND the global spine controller
-        run inside the compiled scan (:func:`fabric_controller_apply`)."""
-        chunk = fabric_controller_chunk(
-            self.cfg, self.fcfg, self.controllers[0].cfg,
-            self.spine_controller.cfg, self.server_cfg, self.client_cfg,
-            self.key_size, period_w, n_periods)
-        ra = jnp.asarray([c.active_size for c in self.controllers],
-                         jnp.int32)
-        sa = jnp.asarray(self.spine_controller.active_size, jnp.int32)
-        carry, ra, sa, ys = chunk(self.wl.arrays, self.carry, ra, sa)
-        self.carry = carry
-        for i, c in enumerate(self.controllers):
-            c.active_size = int(ra[i])
-        self.spine_controller.active_size = int(sa)
-        return fabric_metrics_dict(ys)
-
-    def run(self, sim_seconds: float, chunk_windows: int = 256,
-            controller_period_s: float | None = None) -> FabricResult:
-        from .simulator import chunked_run, period_windows
-        c = self.cfg
-        total = int(round(sim_seconds / (c.window_us * 1e-6)))
-        period_w = period_windows(controller_period_s, c.window_us)
-        has_ctrl = (c.scheme == "orbitcache"
-                    or self.fcfg.spine_scheme == "orbitcache")
-        traces = chunked_run(total, chunk_windows, period_w, has_ctrl,
-                             self.run_periods, self.run_windows)
-        merged = {k: np.concatenate([t[k] for t in traces], axis=0)
-                  for k in traces[0]}
-        hist_sw = np.asarray(self.carry.racks.clients.hist_switch)
-        hist_srv = np.asarray(self.carry.racks.clients.hist_server)
-        res = FabricResult(window_us=c.window_us)
-        for i in range(self.fcfg.n_racks):
-            r = SimResult(
-                window_us=c.window_us,
-                traces={k[len("rack_"):]: v[:, i] for k, v in merged.items()
-                        if k.startswith("rack_")},
-            )
-            r.hist_switch = hist_sw[i]
-            r.hist_server = hist_srv[i]
-            r.info = dict(scheme=c.scheme, rack=i)
-            res.racks.append(r)
-        res.spine = dict(
-            scheme=self.fcfg.spine_scheme,
-            active_size=self.spine_controller.active_size,
-            remote=merged["spine_remote"],
-            hits=merged["spine_hits"],
-            served=merged["spine_served"],
-            fwd=merged["spine_fwd"],
-            in_drops=merged["spine_in_drops"],
-            fwd_drops=merged["spine_fwd_drops"],
-            hist_switch=np.asarray(self.carry.spine_clients.hist_switch),
-            rx_switch=int(self.carry.spine_clients.rx_switch),
-            mismatches=int(self.carry.spine_clients.mismatches),
-        )
-        return res

@@ -1,13 +1,14 @@
-"""Batched multi-rack sweeps: one jitted scan runs N sweep points at once.
+"""One fleet per topology: N sweep points advance in one jitted scan.
 
 The paper's evaluation (Figs. 9–18) — like NetCache's and TurboKV's — is
 dominated by wide parameter sweeps: offered load x zipf skew x value-size
-mix x scheme seeds.  Running each point as its own serial
-:class:`~repro.kvstore.simulator.RackSimulator` leaves the accelerator
-idle between many small dispatches; :class:`BatchedRackSimulator` instead
-``vmap``s the shared :func:`~repro.kvstore.simulator.window_step` over a
-leading rack axis, so a whole sweep advances in lockstep inside a single
-compiled ``lax.scan`` chunk.
+mix x scheme seeds.  :class:`BatchedRackSimulator` ``vmap``s the shared
+:func:`~repro.kvstore.simulator.window_step` over a leading point axis, so
+a whole sweep advances in lockstep inside a single compiled ``lax.scan``
+chunk.  It is the only rack simulator: :class:`RackSimulator` is a view of a
+one-point fleet, and :class:`FabricSimulator` likewise of a one-point
+:class:`BatchedFabricSimulator`.  A view hides the point axis and nothing
+else, so every run of either drives the program the chip runs.
 
 Sweep axes that change *data* (offered load, write ratio, Zipf table, value
 sizes, RNG seed) batch freely.  Axes that change *shapes or control flow*
@@ -43,20 +44,32 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.baselines.netcache import netcache_install
 from repro.core.controller import CacheController, ControllerConfig
 from repro.obs import span
 
 from . import client as cl
+from .fabric_sim import (
+    FabricConfig,
+    FabricResult,
+    fabric_chunk,
+    fabric_controller_chunk,
+    fabric_metrics_dict,
+    init_fabric_carry,
+    preload_spine,
+)
+from .server import server_reports
 from .simulator import (
     RackConfig,
     SimCarry,
     SimResult,
     build_fetch_batch,
+    chunked_run,
     controller_chunk_body,
     init_carry,
     make_client_config,
     make_server_config,
+    period_windows,
+    preload_policy,
     tree_stack as _tree_stack,
     tree_take as _tree_take,
     window_step,
@@ -70,7 +83,7 @@ def compiled_batched_chunk(cfg: RackConfig, server_cfg, client_cfg,
     """Jitted vmapped ``n``-window chunk: ``(wl, carry) -> (carry, metrics)``.
 
     ``wl_axes`` is a WorkloadArrays of vmap in_axes (0 = stacked per point,
-    None = shared); the batched carry is donated like the serial path.
+    None = shared); the batched carry is donated.
     The RNG seed is host-side only, so fleets differing only by seed share
     one compilation; the active kernel backend is part of the cache key
     because it is baked in at trace time.
@@ -100,11 +113,12 @@ def compiled_batched_controller_chunk(cfg: RackConfig, ctrl_cfg,
                                       server_cfg, client_cfg, key_size: int,
                                       period_w: int, n_periods: int,
                                       wl_axes: WorkloadArrays):
-    """Vmapped twin of ``simulator.compiled_controller_chunk``: every sweep
-    point runs ``n_periods`` whole control-plane periods — windows AND the
-    traced cache update — inside one compiled scan, with ``active_size``
-    a per-point carry vector.  This is what makes batched Fig. 18 churn
-    sweeps possible: no host-side per-point state surgery between chunks.
+    """Jitted ``simulator.controller_chunk_body`` vmapped over points:
+    every sweep point runs ``n_periods`` whole control-plane periods —
+    windows AND the traced cache update — inside one compiled scan, with
+    ``active_size`` a per-point carry vector.  This is what makes batched
+    Fig. 18 churn sweeps possible: no host-side per-point state surgery
+    between chunks.
     """
     from repro.kernels import kernel_backend
     return _compiled_batched_controller_chunk(
@@ -274,36 +288,17 @@ class BatchedRackSimulator:
 
     # ------------------------------------------------------------- preload
     def preload(self, keys: Sequence[np.ndarray] | None = None) -> None:
-        """Install each point's hot set, then run warm-up windows."""
-        c = self.cfg
-        if c.scheme == "nocache":
+        """Install each point's hot set (``keys[i]``, default its
+        workload's hottest), then run the 16 warm-up windows."""
+        if self.cfg.scheme == "nocache":
             return
-        if keys is None:
-            k = (c.cache_entries if c.scheme == "orbitcache"
-                 else c.netcache_entries)
-            keys = [w.hottest_keys(k) for w in self.workloads]
-        if c.scheme == "orbitcache":
-            pols, fbs = [], []
-            for i in range(self.n_points):
-                pol, fetches = self.controllers[i].preload(
-                    _tree_take(self.carry.policy, i), np.asarray(keys[i]))
-                pols.append(pol)
-                fbs.append(build_fetch_batch(c, self.workloads[i].vlen,
-                                             fetches))
-            self.carry = self.carry._replace(
-                policy=_tree_stack(pols), fetch=_tree_stack(fbs))
-        elif c.scheme == "netcache":
-            pols = []
-            for i in range(self.n_points):
-                ks = np.asarray(keys[i])
-                st, _ = netcache_install(
-                    _tree_take(self.carry.policy, i), ks,
-                    self.workloads[i].vlen_np[ks],
-                    key_size=self.key_size,
-                    value_limit=c.netcache_value_limit,
-                )
-                pols.append(st)
-            self.carry = self.carry._replace(policy=_tree_stack(pols))
+        pols, fetches = zip(*(
+            preload_policy(self.cfg, _tree_take(self.carry.policy, i),
+                           self.controllers[i], self.workloads[i],
+                           None if keys is None else keys[i])
+            for i in range(self.n_points)))
+        self.carry = self.carry._replace(policy=_tree_stack(pols),
+                                         fetch=_tree_stack(fetches))
         # warm: F-REQs reach servers and F-REPs install orbit lines; both
         # caches start measuring with the same windows behind them
         self.run_windows(16)
@@ -350,7 +345,6 @@ class BatchedRackSimulator:
         otherwise the hot set stays as preloaded (all fixed-cache sweeps:
         Figs. 9, 13, 16).
         """
-        from .simulator import chunked_run, period_windows
         c = self.cfg
         total = int(round(sim_seconds / (c.window_us * 1e-6)))
         period_w = period_windows(controller_period_s, c.window_us)
@@ -385,16 +379,21 @@ class BatchedFabricSimulator:
     One fabric per sweep point; the points share the rack/fabric geometry
     and the workload but may differ in rack-local fraction, offered load
     and RNG seeds — the locality-sweep benchmark runs all its points in
-    one compiled scan this way.
+    one compiled scan this way.  Point ``i``'s rack ``r`` is seeded
+    ``seeds[i] + r`` and its homing draws ``seeds[i] + 0x0FAB``; the
+    default is ``seeds[i] = cfg.seed + 1000 * i``.
     """
 
-    def __init__(self, cfg: RackConfig, fcfg, wl: Workload,
+    def __init__(self, cfg: RackConfig, fcfg: FabricConfig, wl: Workload,
                  local_fracs: Sequence[float] | None = None,
                  offered_rps: Sequence[float] | float | None = None,
                  seeds: Sequence[int] | None = None,
                  n_points: int | None = None):
-        from .fabric_sim import FabricSimulator
-
+        if fcfg.spine_lanes % cfg.subrounds or fcfg.fwd_lanes % cfg.subrounds:
+            raise ValueError(
+                f"spine_lanes ({fcfg.spine_lanes}) and fwd_lanes "
+                f"({fcfg.fwd_lanes}) must be multiples of subrounds "
+                f"({cfg.subrounds})")
         n = max(len(local_fracs) if local_fracs is not None else 1,
                 len(offered_rps) if isinstance(offered_rps, (list, tuple))
                 else 1,
@@ -414,60 +413,69 @@ class BatchedFabricSimulator:
                        else [fcfg.local_frac], "local_fracs")
         seeds = _bcast(seeds if seeds is not None
                        else [cfg.seed + 1000 * i for i in range(n)], "seeds")
-        if offered_rps is not None and np.isscalar(offered_rps):
+        if offered_rps is None:
+            offered_rps = wl.cfg.offered_rps
+        if np.isscalar(offered_rps):
             offered_rps = [float(offered_rps)]
-        offered = (_bcast(offered_rps, "offered_rps")
-                   if offered_rps is not None else None)
+        offered = _bcast(offered_rps, "offered_rps")
         self.cfg = cfg
         self.fcfg = fcfg
         self.wl = wl
         self.n_points = n
-        # build each point as a serial FabricSimulator (host-side preload
-        # surgery is per point), then stack the carries
-        self._sims = [
-            FabricSimulator(replace(cfg, seed=seeds[i]), fcfg, wl)
-            for i in range(n)
+        self.server_cfg = make_server_config(cfg)
+        self.client_cfg = make_client_config(cfg)
+        self.key_size = wl.cfg.key_size
+        self.controllers = [
+            [CacheController(ControllerConfig(
+                active_size=cfg.cache_entries, max_size=cfg.cache_entries))
+             for _ in range(fcfg.n_racks)]
+            for _ in range(n)
         ]
-        for i, sim in enumerate(self._sims):
-            sim.set_local_frac(fracs[i])
-        self.server_cfg = self._sims[0].server_cfg
-        self.client_cfg = self._sims[0].client_cfg
-        self.key_size = self._sims[0].key_size
-        self.carry = None  # stacked after preload
-        if offered is not None:
-            for sim, rps in zip(self._sims, offered):
-                sim.set_offered(rps)
+        self.spine_controllers = [
+            CacheController(ControllerConfig(
+                active_size=fcfg.spine_cache_entries,
+                max_size=fcfg.spine_cache_entries,
+                k_report=fcfg.spine_k_report))
+            for _ in range(n)
+        ]
+        self.carry = _tree_stack([
+            init_fabric_carry(cfg, fcfg, self.server_cfg, self.client_cfg,
+                              wl.cfg.num_keys, offered[i],
+                              wl.cfg.write_ratio, fracs[i], seeds[i])
+            for i in range(n)
+        ])
 
     def preload(self, warm_windows: int = 16) -> None:
-        if self._sims is None:
-            raise RuntimeError("fabric sweep already stacked — preload once, "
-                               "before the first run_windows()")
-        # host-side table surgery per point, warm-up batched: the warm
-        # windows run through the SAME vmapped chunk as the measurement,
-        # so no serial fabric step is ever compiled for a sweep
-        warm = any(s.cfg.scheme != "nocache" for s in self._sims)
-        for sim in self._sims:
-            sim.preload(warm_windows=0)
-        self._stack()
-        if warm and warm_windows > 0:
-            self.run_windows(warm_windows)
+        """Install every point's rack hot sets and global spine hot set,
+        then warm up: the warm windows run through the SAME vmapped chunk
+        as the measurement."""
+        c, racks = self.cfg, self.carry.racks
+        installs = [[preload_policy(c, _tree_take(racks.policy, (i, r)),
+                                    self.controllers[i][r], self.wl)
+                     for r in range(self.fcfg.n_racks)]
+                    for i in range(self.n_points)]
 
-    def _stack(self) -> None:
-        self.carry = _tree_stack([s.carry for s in self._sims])
-        self._controllers = [s.controllers for s in self._sims]
-        self._spine_controllers = [s.spine_controller for s in self._sims]
-        # the per-point carries are dead once stacked (and stale after the
-        # first run) — drop them so device state isn't held twice
-        self._sims = None
+        def stack(j):  # [point][rack] -> leaves [N, R, ...]
+            return _tree_stack([_tree_stack([rack[j] for rack in point])
+                                for point in installs])
+
+        spine = _tree_stack([
+            preload_spine(_tree_take(self.carry.spine, i), c, self.fcfg,
+                          self.wl)
+            for i in range(self.n_points)])
+        self.carry = self.carry._replace(
+            racks=racks._replace(policy=stack(0), fetch=stack(1)),
+            spine=spine)
+        if c.scheme != "nocache" and warm_windows > 0:
+            # let rack F-REQs reach servers and F-REPs install orbit lines;
+            # NetCache racks warm alike, as a rack fleet's preload does
+            self.run_windows(warm_windows)
 
     def run_windows(self, n: int) -> dict[str, np.ndarray]:
         """Advance every fabric ``n`` windows; rack traces are
         [N, n, R, ...], spine traces [N, n]."""
-        if self.carry is None:
-            self._stack()
-        from .fabric_sim import fabric_chunk, fabric_metrics_dict
         chunk = fabric_chunk(self.cfg, self.fcfg, self.server_cfg,
-                             self.client_cfg, self.key_size, n, vmapped=True)
+                             self.client_cfg, self.key_size, n)
         carry, ys = chunk(self.wl.arrays, self.carry)
         self.carry = carry
         return fabric_metrics_dict(ys)
@@ -477,22 +485,162 @@ class BatchedFabricSimulator:
         per-rack ToR controllers and every point's global spine controller
         run inside one vmapped compiled scan (active sizes carried as
         [N, R] / [N] vectors)."""
-        if self.carry is None:
-            self._stack()
-        from .fabric_sim import fabric_controller_chunk, fabric_metrics_dict
         chunk = fabric_controller_chunk(
-            self.cfg, self.fcfg, self._controllers[0][0].cfg,
-            self._spine_controllers[0].cfg, self.server_cfg,
-            self.client_cfg, self.key_size, period_w, n_periods,
-            vmapped=True)
+            self.cfg, self.fcfg, self.controllers[0][0].cfg,
+            self.spine_controllers[0].cfg, self.server_cfg,
+            self.client_cfg, self.key_size, period_w, n_periods)
         ra = jnp.asarray([[c.active_size for c in ctrls]
-                          for ctrls in self._controllers], jnp.int32)
-        sa = jnp.asarray([s.active_size for s in self._spine_controllers],
+                          for ctrls in self.controllers], jnp.int32)
+        sa = jnp.asarray([s.active_size for s in self.spine_controllers],
                          jnp.int32)
         carry, ra, sa, ys = chunk(self.wl.arrays, self.carry, ra, sa)
         self.carry = carry
-        for i, ctrls in enumerate(self._controllers):
+        for i, ctrls in enumerate(self.controllers):
             for j, c in enumerate(ctrls):
                 c.active_size = int(ra[i, j])
-            self._spine_controllers[i].active_size = int(sa[i])
+            self.spine_controllers[i].active_size = int(sa[i])
         return fabric_metrics_dict(ys)
+
+    def run(self, sim_seconds: float, chunk_windows: int = 256,
+            controller_period_s: float | None = None) -> list[FabricResult]:
+        """Run every fabric for ``sim_seconds``; one FabricResult per
+        point.  With ``controller_period_s`` set and a cache on either
+        tier, the run is whole periods with every controller in the scan."""
+        c = self.cfg
+        total = int(round(sim_seconds / (c.window_us * 1e-6)))
+        period_w = period_windows(controller_period_s, c.window_us)
+        has_ctrl = (c.scheme == "orbitcache"
+                    or self.fcfg.spine_scheme == "orbitcache")
+        traces = chunked_run(total, chunk_windows, period_w, has_ctrl,
+                             self.run_periods, self.run_windows)
+        merged = {k: np.concatenate([t[k] for t in traces], axis=1)
+                  for k in traces[0]}
+        racks, spine = jax.device_get((self.carry.racks.clients,
+                                       self.carry.spine_clients))
+        results = []
+        for i in range(self.n_points):
+            res = FabricResult(window_us=c.window_us)
+            for r in range(self.fcfg.n_racks):
+                rr = SimResult(
+                    window_us=c.window_us,
+                    traces={k[len("rack_"):]: v[i][:, r]
+                            for k, v in merged.items()
+                            if k.startswith("rack_")},
+                )
+                rr.hist_switch = racks.hist_switch[i, r]
+                rr.hist_server = racks.hist_server[i, r]
+                rr.info = dict(scheme=c.scheme, rack=r)
+                res.racks.append(rr)
+            res.spine = dict(
+                scheme=self.fcfg.spine_scheme,
+                active_size=self.spine_controllers[i].active_size,
+                remote=merged["spine_remote"][i],
+                hits=merged["spine_hits"][i],
+                served=merged["spine_served"][i],
+                fwd=merged["spine_fwd"][i],
+                in_drops=merged["spine_in_drops"][i],
+                fwd_drops=merged["spine_fwd_drops"][i],
+                hist_switch=spine.hist_switch[i],
+                rx_switch=int(spine.rx_switch[i]),
+                mismatches=int(spine.mismatches[i]),
+            )
+            results.append(res)
+        return results
+
+
+# ---------------------------------------------------------------------------
+# one-point views
+# ---------------------------------------------------------------------------
+class _OnePointView:
+    """A fleet of one point with the point axis hidden: ``carry`` and the
+    traces are point 0's, and ``run`` returns its one result."""
+
+    def __init__(self, fleet):
+        self.fleet = fleet
+        self.cfg = fleet.cfg
+        self.server_cfg = fleet.server_cfg
+        self.client_cfg = fleet.client_cfg
+        self.key_size = fleet.key_size
+
+    @property
+    def carry(self):
+        return _tree_take(self.fleet.carry, 0)
+
+    @carry.setter
+    def carry(self, carry) -> None:
+        self.fleet.carry = _tree_stack([carry])
+
+    def run_windows(self, n: int) -> dict[str, np.ndarray]:
+        return {k: v[0] for k, v in self.fleet.run_windows(n).items()}
+
+    def run_periods(self, n_periods: int, period_w: int) -> dict[str, np.ndarray]:
+        return {k: v[0] for k, v in
+                self.fleet.run_periods(n_periods, period_w).items()}
+
+    def run(self, sim_seconds: float, chunk_windows: int = 256,
+            controller_period_s: float | None = None):
+        return self.fleet.run(sim_seconds, chunk_windows,
+                              controller_period_s)[0]
+
+
+class RackSimulator(_OnePointView):
+    """One storage rack under a switch policy: a one-point
+    :class:`BatchedRackSimulator`.  After host-side churn of ``wl``
+    (``Workload.hot_in_swap``), call :meth:`refresh_workloads`, as on the
+    fleet."""
+
+    def __init__(self, cfg: RackConfig, wl: Workload):
+        super().__init__(BatchedRackSimulator(cfg, wl, n_points=1))
+        self.wl = wl
+
+    @property
+    def controller(self) -> CacheController:
+        return self.fleet.controllers[0]
+
+    def set_offered(self, rps: float) -> None:
+        self.fleet.set_offered(rps)
+
+    def set_write_ratio(self, r: float) -> None:
+        self.fleet.set_write_ratio(r)
+
+    def reset_stats(self) -> None:
+        """Zero client histograms/counters (per-phase measurements)."""
+        self.fleet.reset_stats()
+
+    def refresh_workloads(self) -> None:
+        self.fleet.refresh_workloads()
+
+    def preload(self, keys: np.ndarray) -> None:
+        """Install the hot set ``keys`` before measuring (paper §5.1), then
+        run the 16 warm-up windows."""
+        self.fleet.preload([keys])
+
+    def _control_plane_update(self) -> None:
+        """Host-side cache update of an orbitcache rack (switch counters +
+        server top-k reports, §3.8) — the oracle form of
+        :func:`~repro.kvstore.simulator.controller_window_apply`, kept for
+        tests; runs use the traced in-scan path (:meth:`run_periods`)."""
+        carry = self.carry
+        servers, reports = server_reports(carry.servers,
+                                          self.controller.cfg.k_report)
+        sw = carry.policy
+        sw2, info = self.controller.update(
+            sw, reports, int(sw.counters.overflow),
+            int(sw.counters.cached_reqs))
+        self.carry = carry._replace(
+            policy=sw2, servers=servers,
+            fetch=build_fetch_batch(self.cfg, self.wl.vlen, info.fetches))
+
+
+class FabricSimulator(_OnePointView):
+    """R racks + one spine switch advancing in lockstep: a one-point
+    :class:`BatchedFabricSimulator`."""
+
+    def __init__(self, cfg: RackConfig, fcfg: FabricConfig, wl: Workload):
+        super().__init__(BatchedFabricSimulator(cfg, fcfg, wl, n_points=1))
+        self.fcfg = fcfg
+        self.wl = wl
+
+    def preload(self, warm_windows: int = 16) -> None:
+        """Install rack hot sets + the global spine hot set, then warm up."""
+        self.fleet.preload(warm_windows)

@@ -32,9 +32,10 @@ enter the carry), so the per-window ingress assembly is a single axis-1
 concatenation with no transposes of the value payload.  ``window_step`` is
 a module-level pure function over (configs, WorkloadArrays, carry): the
 workload arrays are explicit jit arguments (host-side churn needs no
-retrace) and the same compiled chunk is shared by every simulator with the
-same static config — including the vmapped multi-rack sweeps in
-``repro.kvstore.fleet``.
+retrace) and the same compiled chunk is shared by every fleet with the
+same static config.  This module holds the configs, carries, step
+functions, chunk bodies and results; the fleets that compile and run
+them are in ``repro.kvstore.fleet``.
 
 The orbitcache switch pass is ONE fused ``kernels.subround`` op per
 subround (a single ``pallas_call`` on the kernel backends); the orbit
@@ -45,8 +46,7 @@ copied window to window.
 """
 from __future__ import annotations
 
-import functools
-from dataclasses import dataclass, field, replace
+from dataclasses import dataclass, field
 from typing import Any, NamedTuple
 
 import numpy as np
@@ -81,7 +81,6 @@ from .server import (
     ServerConfig,
     ServerState,
     init_servers,
-    server_reports,
     server_reports_traced,
     server_step,
 )
@@ -145,7 +144,7 @@ class SimCarry(NamedTuple):
 
 
 # ---------------------------------------------------------------------------
-# shared construction helpers (used by RackSimulator, fleet.py, fabric_sim.py)
+# shared construction helpers (used by fleet.py and fabric_sim.py)
 # ---------------------------------------------------------------------------
 def tree_stack(trees):
     """Stack matching pytrees along a new leading axis (sweep/rack axis)."""
@@ -244,6 +243,30 @@ def build_fetch_batch(cfg: RackConfig, vlen_table: jnp.ndarray,
     return interleave(fb, cfg.subrounds)
 
 
+def preload_policy(cfg: RackConfig, policy, controller: CacheController,
+                   wl: Workload, keys: np.ndarray | None = None):
+    """Install one point's hot set before measuring (paper §5.1).
+
+    ``keys`` defaults to the workload's hottest keys, as many as the
+    scheme's cache holds.  OrbitCache installs lookup entries through the
+    host ``controller`` and fetches their values by F-REQs; NetCache
+    installs keys and values directly; NoCache has nothing to install.
+    Returns ``(policy', fetch)``, the F-REQ batch of the next window.
+    """
+    if keys is None:
+        keys = wl.hottest_keys(cfg.cache_entries if cfg.scheme == "orbitcache"
+                               else cfg.netcache_entries)
+    keys = np.asarray(keys)
+    fetches = []
+    if cfg.scheme == "orbitcache":
+        policy, fetches = controller.preload(policy, keys)
+    elif cfg.scheme == "netcache":
+        policy, _ = netcache_install(
+            policy, keys, wl.vlen_np[keys], key_size=wl.cfg.key_size,
+            value_limit=cfg.netcache_value_limit)
+    return policy, build_fetch_batch(cfg, wl.vlen, fetches)
+
+
 def traced_fetch_batch(cfg: RackConfig, vlen_table: jnp.ndarray,
                        fetch_kidx: jnp.ndarray, fetch_valid: jnp.ndarray,
                        ) -> PacketBatch:
@@ -290,10 +313,10 @@ def controller_window_apply(
     pure :func:`~repro.core.controller.controller_step` cache update over
     the switch state's period counters, and queues the resulting F-REQs
     for the next window — the in-scan form of
-    ``RackSimulator._control_plane_update``.  Returns ``(carry', active')``
-    plus the period's :class:`TracedUpdate` and the raw ``(top_kidx,
-    top_est)`` report arrays (the fabric's spine controller merges them
-    across racks).
+    ``fleet.RackSimulator._control_plane_update``.  Returns ``(carry',
+    active')`` plus the period's :class:`TracedUpdate` and the raw
+    ``(top_kidx, top_est)`` report arrays (the fabric's spine controller
+    merges them across racks).
     """
     with stage("repro.controller"):
         servers, top_k, top_e = server_reports_traced(carry.servers,
@@ -311,7 +334,7 @@ def controller_window_apply(
 
 
 # ---------------------------------------------------------------------------
-# the window step (pure; shared by serial and batched simulators)
+# the window step (pure; shared by the rack and fabric fleets)
 # ---------------------------------------------------------------------------
 def generate_requests(
     cfg: RackConfig,
@@ -532,44 +555,14 @@ def process_window(
     return new_carry, metrics
 
 
-def compiled_chunk(cfg: RackConfig, server_cfg: ServerConfig,
-                   client_cfg: cl.ClientConfig, key_size: int, n: int):
-    """Jitted ``n``-window chunk shared across simulator instances.
-
-    Signature: ``(wl: WorkloadArrays, carry) -> (carry, WindowMetrics)``.
-    The carry is donated (the previous window's buffers are dead the moment
-    the scan step returns); workload arrays are regular arguments so
-    host-side churn between chunks is picked up without retracing.  The
-    RNG seed is host-side only, so simulators differing only by seed share
-    one compilation.  The active kernel backend is part of the cache key:
-    it is baked in at trace time, so flipping it must not reuse a stale
-    compilation.
-    """
-    from repro.kernels import kernel_backend
-    return _compiled_chunk(replace(cfg, seed=0), server_cfg, client_cfg,
-                           key_size, n, kernel_backend())
-
-
-@functools.lru_cache(maxsize=None)
-def _compiled_chunk(cfg: RackConfig, server_cfg: ServerConfig,
-                    client_cfg: cl.ClientConfig, key_size: int, n: int,
-                    kernel_backend: str):
-    def body(wl: WorkloadArrays, carry: SimCarry):
-        def step(c, x):
-            return window_step(cfg, server_cfg, client_cfg, key_size, wl, c, x)
-        return jax.lax.scan(step, carry, None, length=n)
-
-    return jax.jit(body, donate_argnums=(1,))
-
-
 def controller_chunk_body(cfg: RackConfig, ctrl_cfg: ControllerConfig,
                           server_cfg: ServerConfig,
                           client_cfg: cl.ClientConfig, key_size: int,
                           period_w: int, n_periods: int):
-    """The period-structured scan body shared by the serial and vmapped
-    controller chunks: ``n_periods`` iterations of (``period_w`` windows,
-    one traced cache update).  No ``lax.cond`` — the update sits at a
-    static position, so the body vmaps over a rack axis unchanged.
+    """The one period-structured scan body: ``n_periods`` iterations of
+    (``period_w`` windows, one traced cache update).  No ``lax.cond`` —
+    the update sits at a static position, so the body vmaps over a point
+    axis unchanged (``fleet.compiled_batched_controller_chunk``).
 
     Signature: ``(wl, carry, active_size) -> (carry', active', metrics,
     TracedUpdate)`` with metrics flattened to a ``[n_periods * period_w,
@@ -595,40 +588,10 @@ def controller_chunk_body(cfg: RackConfig, ctrl_cfg: ControllerConfig,
     return body
 
 
-def compiled_controller_chunk(cfg: RackConfig, ctrl_cfg: ControllerConfig,
-                              server_cfg: ServerConfig,
-                              client_cfg: cl.ClientConfig, key_size: int,
-                              period_w: int, n_periods: int):
-    """Jitted chunk of ``n_periods`` control-plane periods (orbitcache).
-
-    The whole period loop — ``period_w`` windows THEN the traced cache
-    update (server reports, evict/insert, counter reset, F-REQ injection,
-    §3.10 sizing) — runs inside one compiled scan; the only host-visible
-    state between chunks is the carry and the ``active_size`` scalar.
-    Cache policy mirrors :func:`compiled_chunk` (seed normalized out,
-    kernel backend baked in).
-    """
-    from repro.kernels import kernel_backend
-    return _compiled_controller_chunk(
-        replace(cfg, seed=0), ctrl_cfg, server_cfg, client_cfg, key_size,
-        period_w, n_periods, kernel_backend())
-
-
-@functools.lru_cache(maxsize=None)
-def _compiled_controller_chunk(cfg, ctrl_cfg, server_cfg, client_cfg,
-                               key_size, period_w, n_periods,
-                               kernel_backend):
-    body = controller_chunk_body(cfg, ctrl_cfg, server_cfg, client_cfg,
-                                 key_size, period_w, n_periods)
-    return jax.jit(body, donate_argnums=(1,))
-
-
 def period_windows(controller_period_s: float | None,
                    window_us: float) -> int | None:
     """Control-plane period length in windows (None = no periodic
-    controller).  The one rounding rule every simulator's ``run()`` must
-    share — a cadence drift between the serial/batched/fabric drivers
-    would break their bit-identity guarantees."""
+    controller).  The one rounding rule both fleets' ``run()`` share."""
     if not controller_period_s:
         return None
     return max(1, int(round(controller_period_s / (window_us * 1e-6))))
@@ -636,20 +599,16 @@ def period_windows(controller_period_s: float | None,
 
 def chunked_run(total_windows: int, chunk_windows: int,
                 period_w: int | None, use_traced_controller: bool,
-                run_periods_fn, run_windows_fn,
-                on_period=None) -> list[dict[str, np.ndarray]]:
-    """The one chunking driver behind every simulator's ``run()``.
+                run_periods_fn, run_windows_fn) -> list[dict[str, np.ndarray]]:
+    """The one chunking loop behind every fleet's ``run()``.
 
     Three modes:
 
-    * traced controller (``period_w`` set, the scheme has one): whole
-      periods through ``run_periods_fn`` — chunks of several periods, or
-      one period per chunk when ``on_period`` needs its per-period
-      host callback;
+    * traced controller (``period_w`` set, the scheme has one): chunks of
+      whole periods through ``run_periods_fn``;
     * ``period_w`` without a traced controller (baseline schemes): plain
-      window chunks aligned to the period so ``on_period`` keeps firing
-      on the same cadence (e.g. host-side churn in an apples-to-apples
-      Fig. 18 comparison);
+      window chunks of the same whole periods, so a baseline simulates
+      the same windows as a cache at equal arguments;
     * no period: window chunks rounded to whole chunks (one compilation
       shared across sweep points and schemes).
 
@@ -657,19 +616,13 @@ def chunked_run(total_windows: int, chunk_windows: int,
     the NEAREST multiple of ``period_w`` (minimum one period — a
     controller run needs a full period of traffic), so the duration error
     is bounded by half a period; the no-period mode likewise rounds to
-    whole chunks.  Rates normalize per window either way.  ``on_period``
-    receives the number of windows completed.  Returns the per-chunk
-    trace dicts.
+    whole chunks.  Rates normalize per window either way.  Returns the
+    per-chunk trace dicts.
     """
     traces: list[dict[str, np.ndarray]] = []
     if period_w:
-        # One loop for both modes — a baseline scheme has no cache update
-        # to apply but gets the SAME whole-period duration rounding and
-        # on_period cadence, so cross-scheme comparisons at equal
-        # arguments simulate equal window counts.
         total_periods = max(1, int(round(total_windows / period_w)))
-        periods_per_chunk = (1 if on_period
-                             else max(1, chunk_windows // period_w))
+        periods_per_chunk = max(1, chunk_windows // period_w)
         # shrink to a divisor of total_periods: every chunk then carries
         # the same n_periods, so the (lru-cached, n_periods-keyed) scan
         # compiles exactly once per run — a remainder chunk would compile
@@ -678,12 +631,8 @@ def chunked_run(total_windows: int, chunk_windows: int,
             periods_per_chunk -= 1
         step = (run_periods_fn if use_traced_controller
                 else (lambda n_p, pw: run_windows_fn(n_p * pw)))
-        done_p = 0
-        while done_p < total_periods:
+        for _ in range(total_periods // periods_per_chunk):
             traces.append(step(periods_per_chunk, period_w))
-            done_p += periods_per_chunk
-            if on_period:
-                on_period(done_p * period_w)
     else:
         total = max(chunk_windows,
                     (total_windows // chunk_windows) * chunk_windows)
@@ -758,147 +707,3 @@ class SimResult:
         cum = np.cumsum(h) / total
         i = int(np.searchsorted(cum, q))
         return float(edges[min(i + 1, len(edges) - 1)])
-
-
-class RackSimulator:
-    """One storage rack under a switch policy."""
-
-    def __init__(self, cfg: RackConfig, wl: Workload):
-        self.cfg = cfg
-        self.wl = wl
-        self.server_cfg = make_server_config(cfg)
-        self.client_cfg = make_client_config(cfg)
-        self.key_size = wl.cfg.key_size
-        self.controller = CacheController(ControllerConfig(
-            active_size=cfg.cache_entries, max_size=cfg.cache_entries,
-        ))
-        self.carry = self._init_carry()
-
-    # -- dynamic knobs (no recompilation) -------------------------------------
-    def set_offered(self, rps: float) -> None:
-        self.carry = self.carry._replace(
-            offered=jnp.float32(rps * self.cfg.window_us * 1e-6))
-
-    def set_write_ratio(self, r: float) -> None:
-        self.carry = self.carry._replace(write_ratio=jnp.float32(r))
-
-    def reset_stats(self) -> None:
-        """Zero client histograms/counters (per-phase measurements)."""
-        self.carry = self.carry._replace(clients=cl.init_clients(self.client_cfg)._replace(
-            next_seq=self.carry.clients.next_seq,
-            crn_kidx=self.carry.clients.crn_kidx,
-            crn_n=self.carry.clients.crn_n,
-        ))
-
-    # ------------------------------------------------------------------ setup
-    def _init_carry(self) -> SimCarry:
-        return init_carry(
-            self.cfg, self.server_cfg, self.client_cfg,
-            self.wl.cfg.num_keys, self.wl.cfg.offered_rps,
-            self.wl.cfg.write_ratio, self.cfg.seed,
-        )
-
-    # -------------------------------------------------------------- preload
-    def preload(self, keys: np.ndarray) -> None:
-        """Install the hot set before measuring (paper §5.1), then run the
-        16 warm-up windows."""
-        c = self.cfg
-        if c.scheme == "nocache":
-            return
-        if c.scheme == "orbitcache":
-            sw, fetches = self.controller.preload(self.carry.policy, keys)
-            self.carry = self.carry._replace(policy=sw)
-            self.inject_fetches(fetches)
-        elif c.scheme == "netcache":
-            st, n = netcache_install(
-                self.carry.policy, keys, self.wl.vlen_np[keys],
-                key_size=self.wl.cfg.key_size,
-                value_limit=c.netcache_value_limit,
-            )
-            self.carry = self.carry._replace(policy=st)
-            self._installed = n
-        # warm: F-REQs reach servers and F-REPs install orbit lines; both
-        # caches start measuring with the same windows behind them
-        self.run_windows(16)
-
-    def inject_fetches(self, fetches: list[tuple[int, int]]) -> None:
-        """Queue controller F-REQs for the next window (value fetch via the
-        data plane, paper §3.8)."""
-        self.carry = self.carry._replace(
-            fetch=build_fetch_batch(self.cfg, self.wl.vlen, fetches))
-
-    # ------------------------------------------------------------------ run
-    def _chunk(self, n: int):
-        return compiled_chunk(self.cfg, self.server_cfg, self.client_cfg,
-                              self.key_size, n)
-
-    def run_windows(self, n: int) -> dict[str, np.ndarray]:
-        carry, ys = self._chunk(n)(self.wl.arrays, self.carry)
-        self.carry = carry
-        return {k: np.asarray(v) for k, v in ys._asdict().items()}
-
-    def run_periods(self, n_periods: int, period_w: int) -> dict[str, np.ndarray]:
-        """Advance ``n_periods`` control-plane periods of ``period_w``
-        windows each — cache updates run INSIDE the compiled scan (the
-        traced :func:`controller_window_apply`); the host only sees the
-        resulting carry and ``active_size``."""
-        chunk = compiled_controller_chunk(
-            self.cfg, self.controller.cfg, self.server_cfg, self.client_cfg,
-            self.key_size, period_w, n_periods)
-        act = jnp.asarray(self.controller.active_size, jnp.int32)
-        carry, act, ys, upds = chunk(self.wl.arrays, self.carry, act)
-        self.carry = carry
-        self.controller.active_size = int(act)
-        self._last_update = jax.tree.map(np.asarray, upds)
-        return {k: np.asarray(v) for k, v in ys._asdict().items()}
-
-    def run(
-        self,
-        sim_seconds: float,
-        chunk_windows: int = 256,
-        controller_period_s: float | None = None,
-        on_period: Any = None,
-    ) -> SimResult:
-        """Run the rack; optionally run control-plane updates periodically.
-
-        With ``controller_period_s`` set on an orbitcache rack, the run is
-        structured as whole periods and the cache updates happen inside
-        the jitted period scan (no host-side surgery between chunks).
-        ``on_period(sim, windows_done)`` fires after every period for any
-        scheme (baseline schemes run plain window chunks on the period
-        cadence — there is just no cache update to apply)."""
-        c = self.cfg
-        total_windows = int(round(sim_seconds / (c.window_us * 1e-6)))
-        period_w = period_windows(controller_period_s, c.window_us)
-        traces = chunked_run(
-            total_windows, chunk_windows, period_w,
-            c.scheme == "orbitcache", self.run_periods, self.run_windows,
-            on_period=(lambda w: on_period(self, w)) if on_period else None,
-        )
-        merged = {
-            k: np.concatenate([t[k] for t in traces], axis=0)
-            for k in traces[0]
-        }
-        res = SimResult(window_us=c.window_us, traces=merged)
-        res.hist_switch = np.asarray(self.carry.clients.hist_switch)
-        res.hist_server = np.asarray(self.carry.clients.hist_server)
-        res.info = dict(scheme=c.scheme, active_size=self.controller.active_size)
-        return res
-
-    def _control_plane_update(self) -> None:
-        """Host-side cache update (switch counters + server top-k reports,
-        §3.8) — the oracle form of :func:`controller_window_apply`, kept
-        for tests and host-driven experiments; production runs use the
-        traced in-scan path (:meth:`run_periods`)."""
-        if self.cfg.scheme != "orbitcache":
-            return
-        servers, reports = server_reports(
-            self.carry.servers, self.controller.cfg.k_report
-        )
-        sw = self.carry.policy
-        overflow = int(sw.counters.overflow)
-        cached = int(sw.counters.cached_reqs)
-        sw2, info = self.controller.update(sw, reports, overflow, cached)
-        self.carry = self.carry._replace(policy=sw2, servers=servers)
-        self.inject_fetches(info.fetches)
-        self._last_update = info

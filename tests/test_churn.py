@@ -3,9 +3,9 @@
 The quick-profile churn story: hot_in_swap makes every cached key cold;
 periodic traced cache updates (server CMS reports -> evict/insert ->
 F-REQ fetches, all inside the compiled period scan) must re-learn the hot
-set and recover throughput — serially AND batched, with the two paths
-bit-identical on shared seeds (the fleet is a batching transform, not an
-approximation).
+set and recover throughput — in a one-point view AND batched, with the
+two bit-identical on shared seeds (the fleet is a batching transform, not
+an approximation).
 """
 import dataclasses
 
@@ -13,8 +13,8 @@ import jax
 import numpy as np
 import pytest
 
-from repro.kvstore.fleet import BatchedRackSimulator, _tree_take
-from repro.kvstore.simulator import RackConfig, RackSimulator
+from repro.kvstore.fleet import BatchedRackSimulator, RackSimulator, _tree_take
+from repro.kvstore.simulator import RackConfig
 from repro.kvstore.workload import Workload, WorkloadConfig
 
 SMALL = RackConfig(scheme="orbitcache", cache_entries=64, num_servers=8,
@@ -22,10 +22,10 @@ SMALL = RackConfig(scheme="orbitcache", cache_entries=64, num_servers=8,
                    track_popularity=True)
 
 
-def test_serial_and_batched_controller_paths_bit_identical():
-    """Same seed => the serial period scan and batched point 0 produce
-    identical traces AND identical post-run switch state, straight
-    through controller periods and a churn event."""
+def test_one_point_and_batched_controller_paths_bit_identical():
+    """Same seed => a one-point view's period scan and point 0 of a
+    two-point fleet produce identical traces AND identical post-run switch
+    state, straight through controller periods and a churn event."""
     def fresh_wl():
         return Workload(WorkloadConfig(num_keys=20_000, offered_rps=2.0e6))
 
@@ -43,6 +43,7 @@ def test_serial_and_batched_controller_paths_bit_identical():
         if phase:
             wl_s.hot_in_swap(32)
             wl_b.hot_in_swap(32)
+            sim.refresh_workloads()
             bsim.refresh_workloads()
         want_traces.append(sim.run_periods(2, 16))
         got_traces.append(bsim.run_periods(2, 16))

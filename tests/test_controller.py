@@ -253,7 +253,8 @@ def test_simulator_counters_reflect_only_current_period():
     """Through the real rack: after a control-plane update the switch
     counters restart from zero, so the next period's overflow equals that
     period's trace, not the lifetime total."""
-    from repro.kvstore.simulator import RackConfig, RackSimulator
+    from repro.kvstore.fleet import RackSimulator
+    from repro.kvstore.simulator import RackConfig
     from repro.kvstore.workload import Workload, WorkloadConfig
     wl = Workload(WorkloadConfig(num_keys=5_000, offered_rps=1.5e6))
     cfg = RackConfig(scheme="orbitcache", cache_entries=16, num_servers=2,

@@ -141,6 +141,7 @@ def test_netcache_window_accounting_lint_clean():
 def test_netcache_spine_accounting_lint_clean():
     from repro.kvstore import fabric_sim as fs
     from repro.kvstore import simulator as sim
+    from repro.kvstore.fleet import FabricSimulator
     from repro.kvstore.workload import Workload, WorkloadConfig
     cfg = sim.RackConfig(scheme="orbitcache", cache_entries=8, num_servers=2,
                          client_batch=16, fetch_lanes=8, value_pad=64,
@@ -151,7 +152,7 @@ def test_netcache_spine_accounting_lint_clean():
                            spine_netcache_entries=16,
                            spine_netcache_table=1 << 8)
     wl = Workload(WorkloadConfig(num_keys=64, offered_rps=1e5))
-    fsim = fs.FabricSimulator(cfg, fcfg, wl)
+    fsim = FabricSimulator(cfg, fcfg, wl)
     found = _dtype_rule_findings(
         "fabric.netcache_spine",
         lambda w, c: fs.fabric_window_step(cfg, fcfg, fsim.server_cfg,

@@ -16,12 +16,12 @@ import pytest
 
 from repro.core import fabric as fb
 from repro.core.types import OP_R_REQ, empty_batch
-from repro.kvstore.fabric_sim import (
-    FabricConfig,
+from repro.kvstore.fabric_sim import FabricConfig, preload_spine
+from repro.kvstore.fleet import (
+    BatchedFabricSimulator,
+    BatchedRackSimulator,
     FabricSimulator,
-    preload_spine,
 )
-from repro.kvstore.fleet import BatchedFabricSimulator, BatchedRackSimulator
 from repro.kvstore.simulator import RackConfig
 from repro.kvstore.workload import Workload, WorkloadConfig
 
@@ -243,28 +243,36 @@ def test_fabric_remote_requests_reach_owning_rack_servers():
     assert served_total > 0
 
 
-def test_batched_fabric_matches_serial_fabric():
-    """The vmapped fabric sweep is bit-identical per point to serial
-    FabricSimulator runs with the same seeds/locality."""
+@pytest.mark.parametrize("scheme", ["orbitcache", "netcache", "nocache"])
+def test_batched_fabric_matches_one_point_fabric(scheme):
+    """The vmapped fabric sweep is bit-identical per point to one-point
+    views (``FabricSimulator``) with the same seeds/locality — through the
+    fleet's own preload of every rack scheme, a window chunk and ``run``."""
     wl = _small_wl()
-    cfg = _small_cfg("orbitcache")
+    cfg = _small_cfg(scheme)
     fcfg = FabricConfig(n_racks=2, spine_scheme="orbitcache",
                         spine_lanes=64, fwd_lanes=32, spine_cache_entries=32)
     fracs = [1.0, 0.5]
     bf = BatchedFabricSimulator(cfg, fcfg, wl, local_fracs=fracs)
     bf.preload(warm_windows=2)
-    serial = []
-    from dataclasses import replace
+    one_point = []
     for i, frac in enumerate(fracs):
-        s = FabricSimulator(replace(cfg, seed=cfg.seed + 1000 * i), fcfg, wl)
-        s.set_local_frac(frac)
+        s = FabricSimulator(dataclasses.replace(cfg, seed=cfg.seed + 1000 * i),
+                            dataclasses.replace(fcfg, local_frac=frac), wl)
         s.preload(warm_windows=2)
         s.run_windows(4)
-        serial.append(s)
+        one_point.append(s)
     bf.run_windows(4)
-    for i, s in enumerate(serial):
+    for i, s in enumerate(one_point):
         _assert_rack_trees_equal(
             jax.tree.map(lambda x: x[i], bf.carry), s.carry)
+    seconds = 4 * cfg.window_us * 1e-6
+    got = bf.run(seconds, chunk_windows=4)
+    for i, s in enumerate(one_point):
+        want = s.run(seconds, chunk_windows=4)
+        for g, w in zip(got[i].racks, want.racks):
+            _assert_rack_trees_equal(g.traces, w.traces)
+        _assert_rack_trees_equal(got[i].spine, want.spine)
 
 
 def test_spine_preload_installs_global_hot_set():

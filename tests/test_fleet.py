@@ -1,8 +1,8 @@
-"""BatchedRackSimulator: vmapped sweep points == serial RackSimulator runs.
+"""BatchedRackSimulator: point i of an N-point fleet == a one-point view.
 
-Each batched point must reproduce the serial simulator exactly (same RNG
-seed => bit-identical traces): the fleet is a pure batching transform, not
-an approximation.
+Each batched point must reproduce a one-point fleet (``RackSimulator``)
+exactly (same RNG seed => bit-identical traces): points are independent
+under vmap, and stacked and shared workload leaves agree.
 """
 import dataclasses
 
@@ -10,8 +10,8 @@ import jax
 import numpy as np
 import pytest
 
-from repro.kvstore.fleet import BatchedRackSimulator, _tree_take
-from repro.kvstore.simulator import RackConfig, RackSimulator
+from repro.kvstore.fleet import BatchedRackSimulator, RackSimulator, _tree_take
+from repro.kvstore.simulator import RackConfig
 from repro.kvstore.workload import Workload, WorkloadArrays, WorkloadConfig
 
 CFG = RackConfig(scheme="orbitcache", cache_entries=64, num_servers=8,
@@ -23,7 +23,7 @@ def wl():
     return Workload(WorkloadConfig(num_keys=20_000, offered_rps=2.0e6))
 
 
-def _serial(cfg, wl, seed, windows=24):
+def _one_point(cfg, wl, seed, windows=24):
     sim = RackSimulator(dataclasses.replace(cfg, seed=seed), wl)
     if cfg.scheme == "orbitcache":
         sim.preload(wl.hottest_keys(cfg.cache_entries))
@@ -33,7 +33,7 @@ def _serial(cfg, wl, seed, windows=24):
 
 
 @pytest.mark.parametrize("scheme", ["orbitcache", "netcache", "nocache"])
-def test_batched_points_match_serial(wl, scheme):
+def test_batched_points_match_one_point(wl, scheme):
     cfg = dataclasses.replace(CFG, scheme=scheme)
     bsim = BatchedRackSimulator(cfg, wl, seeds=[0, 3])
     if scheme == "netcache":
@@ -42,7 +42,7 @@ def test_batched_points_match_serial(wl, scheme):
         bsim.preload()
     got = bsim.run_windows(24)
     for i, seed in enumerate((0, 3)):
-        want = _serial(cfg, wl, seed)
+        want = _one_point(cfg, wl, seed)
         for k in want:
             np.testing.assert_array_equal(
                 got[k][i], want[k],
@@ -50,12 +50,12 @@ def test_batched_points_match_serial(wl, scheme):
 
 
 @pytest.mark.parametrize("scheme", ["orbitcache", "netcache"])
-def test_batched_preload_matches_serial_tables(wl, scheme):
+def test_batched_preload_matches_one_point_tables(wl, scheme):
     """Per-point preload under stacked-leaf sharing builds the *same tables*
-    as preloading each rack serially — checked on the policy state right
-    after preload (not just on end-of-run traces).  The skew sweep stacks
-    the CDF leaf while perm/vlen stay shared, so per-point preload runs
-    against the shared-leaf machinery."""
+    as preloading each rack in a one-point view — checked on the policy
+    state right after preload (not just on end-of-run traces).  The skew
+    sweep stacks the CDF leaf while perm/vlen stay shared, so per-point
+    preload runs against the shared-leaf machinery."""
     wl2 = Workload(WorkloadConfig(num_keys=20_000, zipf_alpha=0.9,
                                   offered_rps=2.0e6))
     cfg = dataclasses.replace(CFG, scheme=scheme)
@@ -122,8 +122,8 @@ def test_batched_rejects_mismatched_points(wl):
 
 @pytest.mark.parametrize("scheme", ["orbitcache", "netcache"])
 def test_preloads_run_the_warm_windows(wl, scheme):
-    """Both caches' preloads, serial and batched, end with the same 16
-    warm-up windows behind them, so a sweep's first measured window is
+    """Both caches' preloads, one-point view and fleet, end with the same
+    16 warm-up windows behind them, so a sweep's first measured window is
     the 17th whichever switch it runs."""
     cfg = dataclasses.replace(CFG, scheme=scheme)
     keys = wl.hottest_keys(64 if scheme == "orbitcache" else 2000)

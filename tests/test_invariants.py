@@ -20,7 +20,8 @@ import numpy as np
 import pytest
 
 from repro.core.types import COUNTER_DTYPE, sat_add
-from repro.kvstore.simulator import RackConfig, RackSimulator
+from repro.kvstore.fleet import RackSimulator
+from repro.kvstore.simulator import RackConfig
 from repro.kvstore.workload import Workload, WorkloadConfig
 
 RNG = np.random.default_rng(20260727)

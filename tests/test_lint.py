@@ -115,8 +115,8 @@ _JAXPR_RULES = ["no-scatter", "single-pallas-call", "dtype-promotion",
 
 
 @pytest.mark.parametrize("entry_name", [
-    "subround_pipeline", "window_pipeline", "compiled_controller_chunk",
-    "fleet.window_step", "fabric_window_step", "fabric_controller_chunk",
+    "subround_pipeline", "window_pipeline", "fleet.controller_chunk",
+    "fleet.window_step", "fabric_chunk", "fabric_controller_chunk",
 ])
 def test_production_entry_jaxpr_rules_clean(entry_name):
     entries = build_entry_points([entry_name])

@@ -197,7 +197,8 @@ def _window_pair(scheme):
     from test_switch_regression import _composed_window_step
 
     from repro.kvstore import simulator as sim_mod
-    from repro.kvstore.simulator import RackConfig, RackSimulator
+    from repro.kvstore.fleet import RackSimulator
+    from repro.kvstore.simulator import RackConfig
     from repro.kvstore.workload import Workload, WorkloadConfig
 
     key = (scheme, kn.kernel_backend())

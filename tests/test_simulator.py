@@ -6,7 +6,8 @@ balancing physics on CPU-sized runs.
 import numpy as np
 import pytest
 
-from repro.kvstore.simulator import RackConfig, RackSimulator
+from repro.kvstore.fleet import RackSimulator
+from repro.kvstore.simulator import RackConfig
 from repro.kvstore.workload import Workload, WorkloadConfig, production_workload
 
 N_KEYS = 200_000
@@ -83,6 +84,7 @@ def test_dynamic_hot_in_recovers():
     sim.preload(wl2.hottest_keys(128))
     before = sim.run(0.03).throughput_rps(burn_frac=0.5)
     wl2.hot_in_swap(128)           # all cache entries become cold
+    sim.refresh_workloads()
     during = sim.run(0.03, controller_period_s=0.01)
     after = sim.run(0.03).throughput_rps(burn_frac=0.5)
     # controller re-learns the hot set and recovers most throughput

@@ -340,7 +340,8 @@ def _composed_window_step(cfg, server_cfg, client_cfg, key_size, wl, carry):
 @pytest.mark.parametrize("scheme", ["orbitcache", "netcache", "nocache"])
 def test_window_step_bit_identical_to_composed(scheme):
     from repro.kvstore import simulator as sim_mod
-    from repro.kvstore.simulator import RackConfig, RackSimulator
+    from repro.kvstore.fleet import RackSimulator
+    from repro.kvstore.simulator import RackConfig
     from repro.kvstore.workload import Workload, WorkloadConfig
 
     wl = Workload(WorkloadConfig(num_keys=5_000, offered_rps=1.5e6,
@@ -384,7 +385,8 @@ def test_window_step_routes_through_pipeline(monkeypatch):
     """window_step's orbitcache branch runs on core.pipeline (trace-time
     spy), i.e. the value-light PipelineCarry scan, not the composed path."""
     from repro.kvstore import simulator as sim_mod
-    from repro.kvstore.simulator import RackConfig, RackSimulator
+    from repro.kvstore.fleet import RackSimulator
+    from repro.kvstore.simulator import RackConfig
     from repro.kvstore.workload import Workload, WorkloadConfig
 
     calls = []
